@@ -46,25 +46,21 @@ ROOT = Path(__file__).resolve().parent.parent
 #: the pinned chaos plan (see repro.search.faults.FaultPlan).  attempts=1
 #: keeps every fault transient — retries succeed, nothing is quarantined
 #: in the pool, so the frontier-identity gate stays exact.
-FAULT_PLAN = {"seed": 7, "worker_crash": 0.25, "worker_hang": 0.06,
-              "torn_write": 0.30, "hang_s": 60.0, "attempts": 1}
-
 #: per-future timeout for the FAULT-INJECTED runs only, so injected
 #: hangs (hang_s=60) resolve in seconds.  The clean run keeps the stock
 #: timeout: a cold ILP solve can legitimately take longer than this, and
 #: a spurious timeout on the baseline would be a self-inflicted fault.
 POOL_TIMEOUT_S = 10.0
 
+FAULT_PLAN = {"seed": 7, "worker_crash": 0.25, "worker_hang": 0.06,
+              "torn_write": 0.30, "hang_s": 60.0, "attempts": 1,
+              "pool_timeout_s": POOL_TIMEOUT_S}
 
-def _child_env(fault_plan: dict | None) -> dict:
+
+def _child_env() -> dict:
     env = os.environ.copy()
     env["PYTHONPATH"] = (str(ROOT / "src") + os.pathsep
                          + env.get("PYTHONPATH", ""))
-    env.pop("REPRO_FAULTS", None)
-    env.pop("REPRO_POOL_TIMEOUT_S", None)
-    if fault_plan is not None:
-        env["REPRO_FAULTS"] = json.dumps(fault_plan)
-        env["REPRO_POOL_TIMEOUT_S"] = repr(POOL_TIMEOUT_S)
     return env
 
 
@@ -74,8 +70,10 @@ def _run_suite(label: str, *, json_path: Path, store: Path, checkpoint: Path,
            "--subset", "fast", "--converge", "--jobs", "2",
            "--store", str(store), "--checkpoint", str(checkpoint),
            "--json", str(json_path)]
+    if fault_plan is not None:
+        cmd += ["--faults", json.dumps(fault_plan)]
     print(f"chaos_suite,RUN,0,{label}: {' '.join(cmd[1:])}", flush=True)
-    proc = subprocess.run(cmd, env=_child_env(fault_plan), cwd=ROOT,
+    proc = subprocess.run(cmd, env=_child_env(), cwd=ROOT,
                           timeout=timeout)
     print(f"chaos_suite,EXIT,0,{label} returncode={proc.returncode}",
           flush=True)

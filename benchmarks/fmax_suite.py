@@ -70,6 +70,7 @@ import json
 import os
 import time
 
+from repro import compile_cache
 from repro.analysis import analysis_counts, reset_analysis_counts
 from repro.core import (FloorplanCache, InfeasibleError, Interval,
                         SearchPoint, SearchSpace, analyze_timing,
@@ -79,9 +80,10 @@ from repro.core import (FloorplanCache, InfeasibleError, Interval,
                         timed_pool_simulations)
 from repro.fpga import benchmarks as B, grid_for
 from repro.obs import bench_obs_block, trace as obs_trace
-from repro.search import (DiskFloorplanStore, fault_counts, pool_counts,
-                          reset_fault_counts, reset_pool_counts,
-                          reset_store_counts, store_counts)
+from repro.search import (DiskFloorplanStore, FaultPlan, fault_counts,
+                          install_faults, pool_counts, reset_fault_counts,
+                          reset_pool_counts, reset_store_counts,
+                          store_counts)
 from repro.search.faults import active_plan
 from repro.search.pool import pool_task_stats
 from repro.search.store import store_lookup_stats
@@ -462,15 +464,23 @@ if __name__ == "__main__":
                     help="write the run's span trace as Chrome/Perfetto "
                          "trace_event JSON at PATH (open in ui.perfetto.dev"
                          "; summarize with python -m repro.obs)")
+    ap.add_argument("--faults", default=None, metavar="JSON",
+                    help="converged mode: run under this seeded fault plan "
+                         "(a repro.search.FaultPlan as JSON; the chaos "
+                         "drill's)")
     args = ap.parse_args()
+    compile_cache.configure()
     sim = None if args.no_sim else (args.firings or None)
     subset = FAST_SUBSET if args.subset == "fast" else None
     if args.converge:
-        main_converged(sim_firings=sim, subset=subset,
-                       json_path=args.json_path, jobs=args.jobs,
-                       proposer=args.proposer, backend=args.backend,
-                       store=args.store, checkpoint=args.checkpoint,
-                       trace_path=args.trace_path)
+        plan = (FaultPlan.from_dict(json.loads(args.faults)) if args.faults
+                else None)
+        with install_faults(plan):
+            main_converged(sim_firings=sim, subset=subset,
+                           json_path=args.json_path, jobs=args.jobs,
+                           proposer=args.proposer, backend=args.backend,
+                           store=args.store, checkpoint=args.checkpoint,
+                           trace_path=args.trace_path)
     else:
         main(sim_firings=sim, subset=subset, json_path=args.json_path,
              backend=args.backend, trace_path=args.trace_path)

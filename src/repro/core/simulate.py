@@ -66,10 +66,7 @@ from ..obs import metrics as _metrics
 from ..obs import trace as _trace
 from .graph import TaskGraph
 
-try:  # NumPy is a hard dependency of the repo, but keep the engine gated.
-    import numpy as _np
-except ModuleNotFoundError:  # pragma: no cover
-    _np = None
+import numpy as _np
 
 
 @dataclasses.dataclass
@@ -121,9 +118,9 @@ class SimJob:
 # engine run, one per vectorized array-sweep (NumPy or jax-jitted).
 # Benchmark drivers read these to prove (and CI to enforce) that a suite's
 # simulation phase stayed batched instead of degrading to per-job Python
-# loops.  "fallback" ticks whenever ``backend="auto"`` silently degrades
-# below the backend it would normally pick (no NumPy, or knobs outside the
-# jax sweep's int32 range) — CI gates assert it stays zero.
+# loops.  "fallback" ticks whenever ``backend="auto"`` degrades below the
+# backend it would normally pick (knobs outside the jax sweep's int32
+# range) — CI gates assert it stays zero.
 _ENGINE_INVOCATIONS = _metrics.group(
     "sim.engine",
     {"event": 0, "cycle": 0, "numpy": 0, "jax": 0, "fallback": 0})
@@ -137,26 +134,6 @@ def reset_engine_counts() -> None:
 def engine_counts() -> dict[str, int]:
     """Snapshot of engine invocations since the last reset."""
     return dict(_ENGINE_INVOCATIONS)
-
-
-_JAX_READY: bool | None = None
-
-
-def _jax_ready() -> bool:
-    """True when the jitted sweep backend is usable (jax importable and
-    NumPy present for the padded-layout builder).  Cached after the first
-    probe; importing jax is the expensive part and happens at most once."""
-    global _JAX_READY
-    if _JAX_READY is None:
-        if _np is None:
-            _JAX_READY = False
-        else:
-            try:
-                from repro.kernels.sim_sweep import HAVE_JAX
-                _JAX_READY = bool(HAVE_JAX)
-            except Exception:  # pragma: no cover - defensive
-                _JAX_READY = False
-    return _JAX_READY
 
 
 def _static_check(graph: TaskGraph, mode: str, *, firings: int,
@@ -530,17 +507,16 @@ def simulate_batch(jobs: Sequence[SimJob | TaskGraph], *, firings: int,
     termination/deadlock checks, so each job's results are exactly those of
     its own event simulation.
 
-    backend — "auto" (default): the jax-jitted padded engine whenever jax
-              is importable and every knob fits the sweep's int32 range;
-              otherwise the padded NumPy engine whenever NumPy is present
-              and there is more than one job; a lone job runs the event
-              engine.  Every degradation below the expected rung (no
-              NumPy at all, or int32-unsafe knobs with jax present) ticks
+    backend — "auto" (default): a lone job runs the event engine; more
+              than one runs the jax-jitted padded engine (on the default
+              device: the TPU where there is one) whenever every knob fits
+              the sweep's int32 range.  Knobs that do not fit degrade to
+              the padded NumPy engine, which ticks
               ``engine_counts()["fallback"]`` and emits a warning.
               "jax": force the jitted sweep (``repro.kernels.sim_sweep``;
-              raises when jax is missing or the knobs overflow int32).
+              raises when the knobs overflow int32).
               "numpy": force the NumPy array engine (works for any mix of
-              topologies; raises only when NumPy itself is missing).
+              topologies).
               "event": force per-job event simulation.
     max_bytes — byte budget for the padded array state (default 1 GiB,
               ``None`` = unlimited).  When the batch's padded allocation
@@ -588,11 +564,7 @@ def simulate_batch(jobs: Sequence[SimJob | TaskGraph], *, firings: int,
                           ii=j.ii)
     if backend not in ("auto", "event", "numpy", "jax"):
         raise ValueError(f"unknown backend {backend!r}")
-    if backend == "numpy" and _np is None:
-        raise ValueError("numpy backend requires NumPy")
     if backend == "jax":
-        if not _jax_ready():
-            raise ValueError("jax backend requires jax (and NumPy)")
         from repro.kernels.sim_sweep import fits_int32
         if not fits_int32(norm, firings, max_cycles):
             raise ValueError(
@@ -601,26 +573,17 @@ def simulate_batch(jobs: Sequence[SimJob | TaskGraph], *, firings: int,
                 "(use backend='numpy' for larger values)")
     resolved = backend
     if backend == "auto":
-        if _np is None:
+        from repro.kernels.sim_sweep import fits_int32
+        if len(norm) <= 1:
+            resolved = "event"          # by design, not a degradation
+        elif fits_int32(norm, firings, max_cycles):
+            resolved = "jax"
+        else:
             _ENGINE_INVOCATIONS["fallback"] += 1
             warnings.warn(
-                "simulate_batch(backend='auto'): NumPy unavailable, "
-                "degrading to per-job event simulation", stacklevel=2)
-            resolved = "event"
-        elif len(norm) <= 1:
-            resolved = "event"          # by design, not a degradation
-        elif _jax_ready():
-            from repro.kernels.sim_sweep import fits_int32
-            if fits_int32(norm, firings, max_cycles):
-                resolved = "jax"
-            else:
-                _ENGINE_INVOCATIONS["fallback"] += 1
-                warnings.warn(
-                    "simulate_batch(backend='auto'): knobs exceed the jax "
-                    "sweep's int32 range, degrading to the NumPy backend",
-                    stacklevel=2)
-                resolved = "numpy"
-        else:
+                "simulate_batch(backend='auto'): knobs exceed the jax "
+                "sweep's int32 range, degrading to the NumPy backend",
+                stacklevel=2)
             resolved = "numpy"
     with _trace.span("simulate.batch", backend=resolved, jobs=len(norm),
                      firings=firings):

@@ -37,7 +37,6 @@ import dataclasses
 from repro.core import InfeasibleError, simulate, simulate_batch
 from repro.core.autobridge import FloorplanCache, autobridge
 from repro.core.devicegrid import SlotGrid
-from repro.core.simulate import _jax_ready
 from repro.analysis import analyze
 from repro.search.engine import explore_design_space, search_until_converged
 from repro.search.pareto import objective_vector
@@ -122,7 +121,7 @@ def _check_verdicts(designs, rep: DifferentialReport) -> list:
 def _check_backends(designs, rep: DifferentialReport, *,
                     firings: int) -> None:
     """Stage 2: one padded numpy sweep over ALL designs vs per-job event,
-    plus (when available) the jitted jax sweep vs numpy, bit-identical."""
+    plus the jitted jax sweep vs numpy, bit-identical."""
     jobs = [d.sim_job() for d in designs]
     np_res = simulate_batch(jobs, firings=firings, backend="numpy")
     ev_res = simulate_batch(jobs, firings=firings, backend="event")
@@ -132,15 +131,14 @@ def _check_backends(designs, rep: DifferentialReport, *,
                 (b.cycles, b.fired, b.deadlocked):
             rep._flag(d, "sim", f"numpy {a.cycles}/{a.deadlocked} vs "
                                 f"event {b.cycles}/{b.deadlocked}")
-    if _jax_ready():
-        jx_res = simulate_batch(jobs, firings=firings, backend="jax")
-        for d, a, b in zip(designs, jx_res, np_res):
-            rep.jax_checked += 1
-            if (a.cycles, a.fired, a.deadlocked, a.steps) != \
-                    (b.cycles, b.fired, b.deadlocked, b.steps):
-                rep._flag(d, "jax", f"jax {a.cycles}/{a.deadlocked}/"
-                                    f"{a.steps} vs numpy {b.cycles}/"
-                                    f"{b.deadlocked}/{b.steps}")
+    jx_res = simulate_batch(jobs, firings=firings, backend="jax")
+    for d, a, b in zip(designs, jx_res, np_res):
+        rep.jax_checked += 1
+        if (a.cycles, a.fired, a.deadlocked, a.steps) != \
+                (b.cycles, b.fired, b.deadlocked, b.steps):
+            rep._flag(d, "jax", f"jax {a.cycles}/{a.deadlocked}/"
+                                f"{a.steps} vs numpy {b.cycles}/"
+                                f"{b.deadlocked}/{b.steps}")
 
 
 def _check_floorplans(designs, rep: DifferentialReport, *,

@@ -8,8 +8,6 @@ axis (and, multi-pod, would span DCN if the model axis crossed pods).
 """
 from __future__ import annotations
 
-import os
-
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -98,9 +96,6 @@ def cache_shardings(cfg: ArchConfig, cache, mesh: Mesh):
             return P()
         name = path[-1] if path else ""
         if name in ("k", "v"):            # (G, B, W, Hkv, D)
-            prefer = os.environ.get("REPRO_KV_SHARD", "heads")
-            if prefer == "context" and leaf.shape[2] % tp == 0:
-                return cut(P(None, daxes, "model", None, None), leaf.ndim)
             if leaf.shape[3] % tp == 0:
                 return cut(P(None, daxes, None, "model", None), leaf.ndim)
             if leaf.shape[2] % tp == 0:   # context parallelism fallback

@@ -22,7 +22,7 @@ import dataclasses
 
 from repro.configs.base import ArchConfig
 from repro.core import InfeasibleError, autobridge
-from .sharding import TpuPlan, tpu_slotgrid
+from .sharding import TpuPlan, col_slots_for, tpu_slotgrid
 from .taskgraph import SHAPES, arch_taskgraph
 
 
@@ -38,8 +38,10 @@ class ClusterState:
 
 
 def replan(cfg: ArchConfig, cell_name: str, state: ClusterState, *,
-           col_slots: int = 4, n_micro: int = 8, seed: int = 0) -> TpuPlan:
+           col_slots: int | None = None, n_micro: int = 8,
+           seed: int = 0) -> TpuPlan:
     """Re-run the co-optimization against the degraded device model."""
+    col_slots = col_slots or col_slots_for(state.model)
     cell = SHAPES[cell_name]
     n_groups = cfg.n_layers // len(cfg.layer_pattern)
     micro_tokens = max(cell.global_batch // n_micro, 1) * \

@@ -20,8 +20,6 @@ Mechanics
 """
 from __future__ import annotations
 
-import os
-
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
@@ -32,20 +30,9 @@ from repro.model.layers import PDTYPE
 from .sharding import TpuPlan
 
 def _stage_shard_map(f, mesh, in_specs, out_specs):
-    """shard_map with "stage" manual, across jax versions: new releases
-    expose ``jax.shard_map(axis_names={"stage"})`` (data/tp stay with
-    GSPMD).  0.4.x only has ``jax.experimental.shard_map``, whose partial-
-    manual mode (``auto=``) lowers axis_index to a PartitionId op the SPMD
-    partitioner rejects — so there we make *every* axis manual: the inner
-    function sees identical values (stage-local slices, replicated along
-    the other axes), trading only the GSPMD overlap along data/tp."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False,
-                             axis_names={"stage"})
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """shard_map with only "stage" manual: data/tp stay with GSPMD."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False, axis_names={"stage"})
 
 
 # parameter-name -> which matmul dim shards over tp
@@ -126,8 +113,6 @@ def from_pipeline_params(params: dict) -> dict:
 def build_train_loss(cfg: ArchConfig, plan: TpuPlan, rmesh: Mesh, *,
                      n_micro: int, remat: bool = True,
                      unroll: bool = False):
-    if os.environ.get("REPRO_PIPE_REMAT") == "0":
-        remat = False
     """Returns loss_fn(params_pipeline, batch) running the floorplanned
     pipeline.  batch: {"tokens": (n_micro, mb, S+1), optional "extra"}."""
     S_stages = plan.n_stages
@@ -187,18 +172,8 @@ def build_train_loss(cfg: ArchConfig, plan: TpuPlan, rmesh: Mesh, *,
                 tgt_out = tokens[jnp.clip(out_idx, 0, n_micro - 1)][:, 1:]
                 is_out = (stage == S_stages - 1) & (out_idx >= 0) & \
                     (out_idx < n_micro)
-                if os.environ.get("REPRO_PIPE_CE", "where") == "cond":
-                    # §Perf iteration: gate the (vocab x d) head matmul so
-                    # only the last stage pays for it (non-last stages take
-                    # the zero branch)
-                    ce = jax.lax.cond(
-                        is_out,
-                        lambda: lm.chunked_ce(params_local, cfg, x, tgt_out),
-                        lambda: jnp.zeros((), jnp.float32))
-                    loss_acc = loss_acc + ce
-                else:
-                    ce = lm.chunked_ce(params_local, cfg, x, tgt_out)
-                    loss_acc = loss_acc + jnp.where(is_out, ce, 0.0)
+                ce = lm.chunked_ce(params_local, cfg, x, tgt_out)
+                loss_acc = loss_acc + jnp.where(is_out, ce, 0.0)
                 # a stage's compute at tick t belongs to microbatch
                 # t - offs[stage]; mask fill/drain garbage
                 my_off = jnp.asarray(offs, jnp.int32)[
@@ -224,11 +199,7 @@ def build_train_loss(cfg: ArchConfig, plan: TpuPlan, rmesh: Mesh, *,
             dmax = max(depths) if depths else 1
             buf_x = jnp.zeros((dmax, mb, seq, cfg.d_model), PDTYPE)
             buf_x0 = jnp.zeros_like(buf_x)
-            # accumulators are rank-1, not rank-0: jax 0.4.x's shard_map
-            # transpose names dim 0 of every residual, which is ill-formed
-            # for scalar residuals (the division keeps the psum'd count as
-            # a residual); a (1,) shape sidesteps it at zero cost.
-            z = jnp.zeros((1,), jnp.float32)
+            z = jnp.zeros((), jnp.float32)
             carry = (buf_x, buf_x0, z, z, z)
             n_ticks = n_micro + total_skew
             if unroll:
@@ -239,8 +210,10 @@ def build_train_loss(cfg: ArchConfig, plan: TpuPlan, rmesh: Mesh, *,
             _, _, loss_acc, aux_acc, count = carry
             loss = jax.lax.psum(loss_acc, "stage") / \
                 jnp.maximum(jax.lax.psum(count, "stage"), 1.0)
-            aux = jax.lax.psum(aux_acc, "stage") / (n_micro * S_stages)
-            return (loss + 0.01 * aux)[0]
+            # every stage adds its own groups' aux for each microbatch, so
+            # the psum is the whole model's aux summed over microbatches
+            aux = jax.lax.psum(aux_acc, "stage") / n_micro
+            return loss + 0.01 * aux
 
         rest = {k: v for k, v in params.items() if k != "groups"}
         # Stage-stack the stage-shared params instead of passing them

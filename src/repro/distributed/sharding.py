@@ -30,10 +30,26 @@ HBM_PER_CHIP = 16e9          # v5e
 DCN_WEIGHT = 4.0             # pod-boundary crossing cost vs 1 ICI hop
 
 
-def tpu_slotgrid(pods: int, data: int, model: int, *, col_slots: int = 4,
+def col_slots_for(model: int) -> int:
+    """Column slots over a model axis of ``model`` chips: up to 4 (ICI
+    subgroups of a 16-chip axis), never more than there are chips, and
+    always a divisor of the axis."""
+    n = min(4, model)
+    while model % n:
+        n -= 1
+    return n
+
+
+def tpu_slotgrid(pods: int, data: int, model: int, *,
+                 col_slots: int | None = None,
                  max_util: float = 0.9) -> SlotGrid:
     """Slot grid over the mesh: (pods) x (col_slots) slots, each owning
-    data * (model/col_slots) chips."""
+    data * (model/col_slots) chips; ``col_slots`` defaults to
+    ``col_slots_for(model)``."""
+    col_slots = col_slots or col_slots_for(model)
+    if model % col_slots:
+        raise ValueError(f"{col_slots} column slots do not divide a model "
+                         f"axis of {model} chips")
     chips_per_slot = data * (model // col_slots)
     cap = {
         "hbm_bytes": chips_per_slot * HBM_PER_CHIP,
@@ -65,9 +81,10 @@ class TpuPlan:
 
 
 def plan_arch(cfg: ArchConfig, cell: ShapeCell, *, pods: int, data: int,
-              model: int, col_slots: int = 4, n_micro: int = 8,
+              model: int, col_slots: int | None = None, n_micro: int = 8,
               seed: int = 0) -> TpuPlan:
     """Run the TAPA co-optimization for (arch x shape x mesh)."""
+    col_slots = col_slots or col_slots_for(model)
     n_groups = cfg.n_layers // len(cfg.layer_pattern)
     micro_tokens = max(cell.global_batch // n_micro, 1) * \
         (cell.seq_len if cell.kind != "decode" else 1)
@@ -122,10 +139,11 @@ def baseline_plan(cfg: ArchConfig, *, pods: int, data: int,
                    crossing_cost=0.0)
 
 
-def refined_mesh(mesh: Mesh, plan: TpuPlan, *, col_slots: int = 4) -> Mesh:
-    """Reshape the production mesh's devices into (stage, data, tp)
-    following the floorplan's slot order.  For the baseline plan the mesh
-    is returned with axes (data, model) merged appropriately."""
+def refined_mesh(mesh: Mesh, plan: TpuPlan) -> Mesh:
+    """Reshape the mesh's devices into (stage, data, tp) following the
+    floorplan's slot order.  For the baseline plan the mesh is returned
+    with axes (data, model) merged appropriately.  Raises ``ValueError``
+    when the floorplan's stages cannot own equal device slabs."""
     devs = mesh.devices
     if devs.ndim == 2:                         # (data, model)
         pods, data, model = 1, devs.shape[0], devs.shape[1]
@@ -134,8 +152,7 @@ def refined_mesh(mesh: Mesh, plan: TpuPlan, *, col_slots: int = 4) -> Mesh:
         pods, data, model = devs.shape
     if plan.mode == "baseline":
         return Mesh(devs.reshape(pods * data, model), ("data", "model"))
-    if plan.tp:
-        col_slots = max(model // plan.tp, 1)
+    col_slots = max(model // plan.tp, 1) if plan.tp else col_slots_for(model)
     tp = model // col_slots
     # slot (r, c) -> devices (data, tp)
     slot_devs = {(r, c): devs[r, :, c * tp:(c + 1) * tp]
@@ -154,20 +171,19 @@ def refined_mesh(mesh: Mesh, plan: TpuPlan, *, col_slots: int = 4) -> Mesh:
         stage_arrays[tgt] = np.concatenate(
             [stage_arrays[tgt], slot_devs[s]], axis=0)
     if len({a.shape for a in stage_arrays}) != 1:
-        # fall back to uniform slabs in stage order (keeps lowering valid;
-        # placement cost already captured in the roofline model)
-        n = plan.n_stages
-        flat = devs.reshape(-1)
-        per = flat.size // n
-        stage_arrays = [flat[i * per:(i + 1) * per].reshape(data, -1)
-                        for i in range(n)]
+        raise ValueError(
+            f"floorplan stages at {used} cannot own equal device slabs of "
+            f"the {devs.shape} mesh: {[a.shape for a in stage_arrays]}")
     devarr = np.stack(stage_arrays)            # (S, data', tp')
     return Mesh(devarr, ("stage", "data", "tp"))
 
 
-def plan_cell(cfg: ArchConfig, cell_name: str, mesh_shape: tuple[int, ...],
-              *, seed: int = 0, mode: str = "tapa") -> TpuPlan:
-    cell = SHAPES[cell_name]
+def plan_cell(cfg: ArchConfig, cell: ShapeCell | str,
+              mesh_shape: tuple[int, ...], *, seed: int = 0,
+              mode: str = "tapa") -> TpuPlan:
+    """Plan one cell (a ``ShapeCell`` or the name of one in ``SHAPES``) on
+    a (data, model) or (pod, data, model) mesh."""
+    cell = SHAPES[cell] if isinstance(cell, str) else cell
     if len(mesh_shape) == 2:
         pods, (data, model) = 1, mesh_shape
     else:

@@ -12,14 +12,13 @@ Two families live here:
   ``jax.jit``-compiled sweep behind ``simulate_batch(backend="jax")``.
 
 Exports resolve lazily (PEP 562): importing ``repro.kernels`` — or
-``repro.core``, which pulls it in for ``simulate_batch`` — never imports
-jax; only touching a ``sim_sweep`` name does, and even that degrades to
-``HAVE_JAX = False`` instead of raising when jax is absent.
+``repro.core``, which pulls it in for ``simulate_batch`` — does not import
+jax; touching a ``sim_sweep`` name does.
 """
 from __future__ import annotations
 
 _PADDED_EXPORTS = ("PaddedBatch", "PaddedGroup", "build_padded_batch")
-_SWEEP_EXPORTS = ("HAVE_JAX", "fits_int32", "reset_sweep_cache_stats",
+_SWEEP_EXPORTS = ("fits_int32", "reset_sweep_cache_stats",
                   "simulate_padded_jax", "sweep_cache_stats")
 
 __all__ = [*_PADDED_EXPORTS, *_SWEEP_EXPORTS]

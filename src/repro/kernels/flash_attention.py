@@ -5,13 +5,15 @@ TPU, so the online-softmax running state (m, l, acc) lives in VMEM scratch
 that persists across KV iterations.  GQA is free: the K/V index map sends
 query head h to KV head h // group.  Causal masking, sliding windows and
 gemma logit soft-caps are fused; fully-maskable KV blocks are skipped via
-``pl.when``.
+``pl.when``.  The per-sequence KV length and query offset are scalar-
+prefetched into SMEM (a (1, 1) VMEM block of a (B, 1) array is not a legal
+TPU tile).
 
 Tiling: Qb x D and Kb x D blocks, 128-aligned for the MXU; head dims that
 are not multiples of 128 are zero-padded by the wrapper.  VMEM per program:
-q/k/v blocks (3 x 32 KiB bf16) + f32 scratch (m, l: 1 KiB; acc: 64 KiB) —
-far under the ~16 MiB budget, leaving room for double buffering of the K/V
-streams.
+q/k/v blocks (3 x 32 KiB bf16) + f32 scratch (m, l: 2 x 64 KiB lane-padded;
+acc: 64 KiB) — far under the ~16 MiB budget, leaving room for double
+buffering of the K/V streams.
 """
 from __future__ import annotations
 
@@ -30,9 +32,10 @@ NEG_INF = -1e30
 def _kernel(klen_ref, qoff_ref, q_ref, k_ref, v_ref, o_ref,
             m_ref, l_ref, acc_ref, *,
             causal, window, softcap, scale, nk, qb, kb, use_klen):
-    j = pl.program_id(3)
+    b = pl.program_id(0)
     i = pl.program_id(2)
-    q_offset = qoff_ref[0, 0]
+    j = pl.program_id(3)
+    q_offset = qoff_ref[b]
 
     @pl.when(j == 0)
     def _init():
@@ -55,9 +58,15 @@ def _kernel(klen_ref, qoff_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(run)
     def _body():
-        q = q_ref[0, 0].astype(jnp.float32) * scale
-        k = k_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (qb, kb)
+        # MXU products in the input dtype, accumulated in float32; float32
+        # inputs ask for full precision (the MXU's default rounds them to
+        # bf16), bf16 inputs multiply exactly anyway
+        v = v_ref[0, 0]
+        prec = (jax.lax.Precision.HIGHEST if v.dtype == jnp.float32
+                else jax.lax.Precision.DEFAULT)
+        s = jax.lax.dot_general(
+            q_ref[0, 0], k_ref[0, 0], (((1,), (1,)), ((), ())),
+            precision=prec, preferred_element_type=jnp.float32) * scale
         if softcap is not None:
             s = jnp.tanh(s / softcap) * softcap
         mask = jnp.ones((qb, kb), bool)
@@ -66,24 +75,24 @@ def _kernel(klen_ref, qoff_ref, q_ref, k_ref, v_ref, o_ref,
         if window is not None:
             mask &= kpos > qpos - window
         if use_klen:
-            mask &= kpos < klen_ref[0, 0]
+            mask &= kpos < klen_ref[b]
         s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(-1))
+        m_prev = m_ref[...]                                   # (qb, 1)
+        m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
+        p = jnp.exp(s - m_new)
         p = jnp.where(mask, p, 0.0)
-        l_ref[...] = l_ref[...] * alpha + p.sum(-1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot(
-            p.astype(jnp.float32), v_ref[0, 0].astype(jnp.float32))
+        l_ref[...] = l_ref[...] * alpha + p.sum(-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
+            p.astype(v.dtype), v, precision=prec,
+            preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     @pl.when(j == nk - 1)
     def _fin():
         lsum = l_ref[...]
-        o_ref[0, 0] = (acc_ref[...] /
-                       jnp.where(lsum > 0, lsum, 1.0)[:, None]
+        o_ref[0, 0] = (acc_ref[...] / jnp.where(lsum > 0, lsum, 1.0)
                        ).astype(o_ref.dtype)
 
 
@@ -110,37 +119,41 @@ def flash_attention(q, k, v, *, causal=True, window=None, softcap=None,
     nq, nk = Sq_p // qb, Skv_p // kb
 
     if kv_len is None:
-        klen = jnp.full((B, 1), Skv, jnp.int32)
+        klen = jnp.full((B,), Skv, jnp.int32)
         use_klen = Skv_p != Skv
     else:
         klen = jnp.broadcast_to(
-            jnp.asarray(kv_len, jnp.int32).reshape(-1, 1), (B, 1))
+            jnp.asarray(kv_len, jnp.int32).reshape(-1), (B,))
         use_klen = True
-    qoff = jnp.broadcast_to(
-        jnp.asarray(q_offset, jnp.int32).reshape(-1, 1), (B, 1))
+    qoff = jnp.broadcast_to(jnp.asarray(q_offset, jnp.int32).reshape(-1),
+                            (B,))
 
     kernel = functools.partial(
         _kernel, causal=causal, window=window, softcap=softcap, scale=scale,
         nk=nk, qb=qb, kb=kb, use_klen=use_klen)
 
-    out = pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,                     # klen, qoff -> SMEM
         grid=(B, Hq, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, h, i, j: (b, 0)),       # klen
-            pl.BlockSpec((1, 1), lambda b, h, i, j: (b, 0)),       # qoff
-            pl.BlockSpec((1, 1, qb, Dp), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, kb, Dp), lambda b, h, i, j: (b, h // g, j, 0)),
-            pl.BlockSpec((1, 1, kb, Dp), lambda b, h, i, j: (b, h // g, j, 0)),
+            pl.BlockSpec((1, 1, qb, Dp), lambda b, h, i, j, *_: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, kb, Dp),
+                         lambda b, h, i, j, *_: (b, h // g, j, 0)),
+            pl.BlockSpec((1, 1, kb, Dp),
+                         lambda b, h, i, j, *_: (b, h // g, j, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, qb, Dp),
-                               lambda b, h, i, j: (b, h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hq, Sq_p, Dp), q.dtype),
+                               lambda b, h, i, j, *_: (b, h, i, 0)),
         scratch_shapes=[
-            pltpu.VMEM((qb,), jnp.float32),
-            pltpu.VMEM((qb,), jnp.float32),
+            pltpu.VMEM((qb, 1), jnp.float32),
+            pltpu.VMEM((qb, 1), jnp.float32),
             pltpu.VMEM((qb, Dp), jnp.float32),
         ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hq, Sq_p, Dp), q.dtype),
         interpret=interpret,
     )(klen, qoff, qp, kp, vp)
     return out.transpose(0, 2, 1, 3)[:, :Sq, :, :D]

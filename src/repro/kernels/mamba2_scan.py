@@ -9,7 +9,9 @@ three MXU-friendly matmuls (the SSD "chunked dual form"):
   state': state * decay(T, 0) + ((dt * x) * decay(T, tau))^T @ B
 
 Grid: (B, H, n_chunks) — the chunk axis is sequential on TPU, so the
-(P, N) state is carried in f32 VMEM scratch across chunk iterations.
+(P, N) state is carried in f32 VMEM scratch across chunk iterations.  The
+within-chunk cumsum of log-decays is a triangular-mask matmul: Mosaic
+lowers no ``jnp.cumsum``.
 Tiling: chunk T=128, P (head dim) and N (state dim) padded to 128.  VMEM
 per program: x/B/C chunks (3 x 64 KiB f32) + decay tables + state
 (64 KiB) — well under budget.
@@ -26,8 +28,16 @@ from jax.experimental.pallas import tpu as pltpu
 DEFAULT_CHUNK = 128
 
 
+def _row_to_col(row, n):
+    """(1, n) -> (n, 1) through a diagonal mask and a lane reduction (a
+    vector transpose that every Mosaic version lowers)."""
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0) ==
+           jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
+    return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+
+
 def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, h0_ref,
-            y_ref, hout_ref, state_ref, *, nc, T):
+            y_ref, hout_ref, state_ref, *, T):
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
@@ -36,32 +46,37 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, h0_ref,
 
     x = x_ref[0, 0].astype(jnp.float32)          # (T, P)
     dt = dt_ref[0, 0].astype(jnp.float32)        # (1, T) row
-    A = a_ref[0, 0, 0, 0]                        # scalar (f32)
+    A = a_ref[0, 0].astype(jnp.float32)          # (1, 1)
     Bm = b_ref[0].astype(jnp.float32)            # (T, N)
     Cm = c_ref[0].astype(jnp.float32)            # (T, N)
     h = state_ref[...]                           # (P, N)
 
-    seg = dt[0] * A                              # (T,) log-decay increments
-    cum = jnp.cumsum(seg)                        # s_t = sum_{tau<=t} seg
-    # decay(t, tau) = exp(s_t - s_tau) for tau <= t (strictly before within
-    # the recurrence the input at tau is included from step tau itself)
-    st = cum[:, None]                            # (T, 1)
-    stau = cum[None, :]                          # (1, T)
+    seg = dt * A                                 # (1, T) log-decay increments
+    # s_t = sum_{tau<=t} seg as a row: seg @ triu (Mosaic lowers no cumsum);
+    # HIGHEST precision because the sums get exponentiated
+    triu = (jax.lax.broadcasted_iota(jnp.int32, (T, T), 0) <=
+            jax.lax.broadcasted_iota(jnp.int32, (T, T), 1)
+            ).astype(jnp.float32)
+    cum = jax.lax.dot(seg, triu, precision=jax.lax.Precision.HIGHEST)
+    cum_c = _row_to_col(cum, T)                  # (T, 1)
+    dt_c = _row_to_col(dt, T)                    # (T, 1)
+    # decay(t, tau) = exp(s_t - s_tau) for tau <= t (the input at tau is
+    # included from step tau itself)
     tri = jax.lax.broadcasted_iota(jnp.int32, (T, T), 0) >= \
         jax.lax.broadcasted_iota(jnp.int32, (T, T), 1)
-    decay = jnp.where(tri, jnp.exp(st - stau), 0.0)
+    decay = jnp.where(tri, jnp.exp(cum_c - cum), 0.0)
 
     G = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())))   # (T, T)
-    dx = x * dt[0][:, None]                                     # (T, P)
-    y = jax.lax.dot((G * decay).astype(jnp.float32), dx)        # (T, P)
+    dx = x * dt_c                                               # (T, P)
+    y = jax.lax.dot(G * decay, dx)                              # (T, P)
     # inter-chunk: h carries state BEFORE this chunk; contribution at step t
     # is C_t . (h * exp(s_t))
-    y = y + jnp.exp(cum)[:, None] * jax.lax.dot_general(
+    y = y + jnp.exp(cum_c) * jax.lax.dot_general(
         Cm, h, (((1,), (1,)), ((), ())))                        # (T, P)
     y_ref[0, 0] = y.astype(y_ref.dtype)
 
-    total = cum[-1]
-    w = jnp.exp(total - cum)[:, None]                           # (T, 1)
+    total = jnp.sum(seg, axis=1, keepdims=True)                 # (1, 1)
+    w = jnp.exp(total - cum_c)                                  # (T, 1)
     new_h = h * jnp.exp(total) + jax.lax.dot_general(
         dx * w, Bm, (((0,), (0,)), ((), ())))                   # (P, N)
     state_ref[...] = new_h
@@ -93,7 +108,7 @@ def mamba2_scan(x, dt, A, B_, C, state=None, *, chunk=DEFAULT_CHUNK,
           jnp.pad(state.astype(jnp.float32),
                   ((0, 0), (0, 0), (0, Pp - P), (0, Np - N))))
 
-    kernel = functools.partial(_kernel, nc=nc, T=T)
+    kernel = functools.partial(_kernel, T=T)
     y, hout = pl.pallas_call(
         kernel,
         grid=(Bsz, H, nc),

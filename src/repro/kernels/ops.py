@@ -1,77 +1,63 @@
-"""Jitted dispatch wrappers over the Pallas kernels and their references.
+"""Dispatch wrappers over the Pallas kernels and their XLA references.
 
-The model layer calls these; ``impl`` selects the backend:
+The model layer calls these.  ``kernel`` selects the implementation:
 
-  * "ref"     — pure-jnp oracle (XLA-compiled; used on CPU and for the
-                dry-run lowering, where XLA's fusion already does well)
-  * "pallas"  — the TPU Pallas kernel (interpret=True on CPU for tests)
+  * False — the pure-jnp reference, compiled by XLA.  It has a VJP, so the
+            differentiated path (``lm.loss_fn`` and the train steps) and
+            every non-TPU backend use it.
+  * True  — the compiled Pallas TPU kernel.  None of the kernels defines a
+            VJP, so only the serving step (``lm.step``) on a TPU asks for
+            it; ``lm.kernels_here()`` makes that choice from the platform.
 
-Default comes from ``repro.kernels.DEFAULT_IMPL`` (env ``REPRO_KERNEL_IMPL``).
+Tests run the kernels on the CPU by calling ``repro.kernels.<name>``
+directly with ``interpret=True``; the references in ``repro.kernels.ref``
+are their oracles.
 """
 from __future__ import annotations
 
-import os
-
-import jax
-
 from . import ref as _ref
-
-DEFAULT_IMPL = os.environ.get("REPRO_KERNEL_IMPL", "ref")
-_INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "auto")
-
-
-def _interpret() -> bool:
-    if _INTERPRET == "auto":
-        return jax.default_backend() != "tpu"
-    return _INTERPRET == "1"
 
 
 def attention(q, k, v, *, causal=True, window=None, softcap=None, scale=None,
-              q_offset=0, kv_len=None, impl=None):
-    impl = impl or DEFAULT_IMPL
-    if impl == "pallas" and q.shape[1] > 1:
-        from . import flash_attention as fa
+              q_offset=0, kv_len=None, kernel=False):
+    if not kernel:
+        return _ref.attention_ref(q, k, v, causal=causal, window=window,
+                                  softcap=softcap, scale=scale,
+                                  q_offset=q_offset, kv_len=kv_len)
+    from . import flash_attention as fa
+    if q.shape[1] > 1:
         return fa.flash_attention(q, k, v, causal=causal, window=window,
                                   softcap=softcap, scale=scale,
-                                  q_offset=q_offset, kv_len=kv_len,
-                                  interpret=_interpret())
-    if impl == "pallas":  # single-token decode
-        from . import flash_attention as fa
-        return fa.decode_attention(q, k, v, softcap=softcap, scale=scale,
-                                   q_offset=q_offset, kv_len=kv_len,
-                                   window=window, interpret=_interpret())
-    return _ref.attention_ref(q, k, v, causal=causal, window=window,
-                              softcap=softcap, scale=scale,
-                              q_offset=q_offset, kv_len=kv_len)
+                                  q_offset=q_offset, kv_len=kv_len)
+    # single-token decode
+    return fa.decode_attention(q, k, v, softcap=softcap, scale=scale,
+                               q_offset=q_offset, kv_len=kv_len,
+                               window=window)
 
 
-def mamba2_scan(x, dt, A, B, C, state=None, *, impl=None):
-    impl = impl or DEFAULT_IMPL
-    if impl == "pallas":
-        from . import mamba2_scan as m2
-        return m2.mamba2_scan(x, dt, A, B, C, state, interpret=_interpret())
-    return _ref.mamba2_scan_ref(x, dt, A, B, C, state)
+def mamba2_scan(x, dt, A, B, C, state=None, *, kernel=False):
+    if not kernel:
+        return _ref.mamba2_scan_ref(x, dt, A, B, C, state)
+    from . import mamba2_scan as m2
+    return m2.mamba2_scan(x, dt, A, B, C, state)
 
 
-def rwkv6_scan(r, k, v, w, u, state=None, *, impl=None):
-    impl = impl or DEFAULT_IMPL
-    if impl == "pallas":
-        from . import rwkv6_scan as r6
-        return r6.rwkv6_scan(r, k, v, w, u, state, interpret=_interpret())
-    return _ref.rwkv6_scan_ref(r, k, v, w, u, state)
+def rwkv6_scan(r, k, v, w, u, state=None, *, kernel=False):
+    if not kernel:
+        return _ref.rwkv6_scan_ref(r, k, v, w, u, state)
+    from . import rwkv6_scan as r6
+    return r6.rwkv6_scan(r, k, v, w, u, state)
 
 
-def burst_gather(table, idx, *, impl=None):
-    impl = impl or DEFAULT_IMPL
-    if impl == "pallas":
-        from . import burst_gather as bg
-        return bg.burst_gather(table, idx, interpret=_interpret())
-    return _ref.burst_gather_ref(table, idx)
+def burst_gather(table, idx, *, kernel=False):
+    if not kernel:
+        return _ref.burst_gather_ref(table, idx)
+    from . import burst_gather as bg
+    return bg.burst_gather(table, idx)
 
 
-def moe_gmm(x, w, group_ids, *, impl=None):
-    impl = impl or DEFAULT_IMPL
-    if impl == "pallas":
-        from . import moe_gmm as gmm
-        return gmm.moe_gmm(x, w, group_ids, interpret=_interpret())
-    return _ref.moe_gmm_ref(x, w, group_ids)
+def moe_gmm(x, w, group_ids, *, kernel=False):
+    if not kernel:
+        return _ref.moe_gmm_ref(x, w, group_ids)
+    from . import moe_gmm as gmm
+    return gmm.moe_gmm(x, w, group_ids)

@@ -15,7 +15,9 @@ cumsum  c_t = sum_{j<=t} log w_j  (c in R^{T x D}):
     state:  S_out = diag(exp(c_T)) S_in + sum_tau (k_tau * exp(c_T - c_tau))^T v_tau
 
 Chunk-local cumsums keep exp(+/-c) bounded (T <= 64 by default), the
-standard numerical treatment for data-dependent decay.
+standard numerical treatment for data-dependent decay.  The cumsum is a
+lower-triangular-mask matmul over the chunk: Mosaic lowers no
+``jnp.cumsum``.
 
 Grid: (B, H, n_chunks), chunk axis sequential, state carried in VMEM
 scratch (f32, D x D padded to 128).
@@ -32,8 +34,26 @@ from jax.experimental.pallas import tpu as pltpu
 DEFAULT_CHUNK = 64
 
 
+def _prefix_sum(x, *, T):
+    """Inclusive prefix sum over the rows of a (T, D) tile as a lower-
+    triangular matmul (``jnp.cumsum`` has no Mosaic lowering).  HIGHEST
+    precision: the sums are log-decays that get exponentiated."""
+    tril = (jax.lax.broadcasted_iota(jnp.int32, (T, T), 0) >=
+            jax.lax.broadcasted_iota(jnp.int32, (T, T), 1)
+            ).astype(jnp.float32)
+    return jax.lax.dot(tril, x, precision=jax.lax.Precision.HIGHEST)
+
+
+def _row_to_col(row, n):
+    """(1, n) -> (n, 1) through a diagonal mask and a lane reduction (a
+    vector transpose that every Mosaic version lowers)."""
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0) ==
+           jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
+    return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+
+
 def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref,
-            y_ref, sout_ref, state_ref, *, T):
+            y_ref, sout_ref, state_ref, *, T, D):
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
@@ -47,7 +67,7 @@ def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref,
     u = u_ref[0, 0].astype(jnp.float32)            # (1, D)
     S = state_ref[...]                             # (D, D)
 
-    c = jnp.cumsum(lw, axis=0)                     # (T, D) inclusive
+    c = _prefix_sum(lw, T=T)                       # (T, D) inclusive
     c_prev = c - lw                                # exclusive cumsum
     r_t = r * jnp.exp(c_prev)                      # (T, D)
     k_t = k * jnp.exp(-c)                          # (T, D)
@@ -65,9 +85,9 @@ def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref,
     y = y + jax.lax.dot(r_t, S)                                     # (T, D)
     y_ref[0, 0] = y.astype(y_ref.dtype)
 
-    cT = c[-1]                                                      # (D,)
-    k_out = k * jnp.exp(cT[None, :] - c)                            # (T, D)
-    S_new = S * jnp.exp(cT)[:, None] + jax.lax.dot_general(
+    cT = jnp.sum(lw, axis=0, keepdims=True)                         # (1, D)
+    k_out = k * jnp.exp(cT - c)                                     # (T, D)
+    S_new = S * jnp.exp(_row_to_col(cT, D)) + jax.lax.dot_general(
         k_out, v, (((0,), (0,)), ((), ())))                         # (D, D)
     state_ref[...] = S_new
     sout_ref[0, 0] = S_new
@@ -102,7 +122,7 @@ def rwkv6_scan(r, k, v, w, u, state=None, *, chunk=DEFAULT_CHUNK,
           jnp.pad(state.astype(jnp.float32),
                   ((0, 0), (0, 0), (0, Dp - D), (0, Dp - D))))
 
-    kernel = functools.partial(_kernel, T=T)
+    kernel = functools.partial(_kernel, T=T, D=Dp)
     y, sout = pl.pallas_call(
         kernel,
         grid=(B, H, nc),

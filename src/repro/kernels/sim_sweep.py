@@ -40,19 +40,10 @@ from __future__ import annotations
 
 import warnings
 
-try:
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-
-    HAVE_JAX = True
-except Exception:  # pragma: no cover - exercised by the no-jax CI leg
-    jax = None
-    jnp = None
-    lax = None
-    HAVE_JAX = False
-
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from ..obs import metrics as _metrics
 from ..obs import trace as _trace
@@ -226,10 +217,7 @@ def _sweep(
     return out_cycles, out_dead, fired, steps
 
 
-if HAVE_JAX:
-    _jit_sweep = jax.jit(_sweep, donate_argnums=(7, 8, 9, 10, 11))
-else:  # pragma: no cover - exercised by the no-jax CI leg
-    _jit_sweep = None
+_jit_sweep = jax.jit(_sweep, donate_argnums=(7, 8, 9, 10, 11))
 
 
 def _pad2(a: np.ndarray, shape: tuple[int, ...], fill) -> np.ndarray:
@@ -244,8 +232,6 @@ def simulate_padded_jax(pb: PaddedBatch, *, firings: int, max_cycles: int):
     Returns ``(cycles, dead, fired, steps)`` as host arrays/ints, sliced
     back to the batch's real (V, T*) shape — feed them to
     ``PaddedBatch.unpack``."""
-    if not HAVE_JAX:  # pragma: no cover - callers gate on HAVE_JAX
-        raise RuntimeError("repro.kernels.sim_sweep requires jax")
     V = pb.V
     V2, T2 = _bucket(V), _bucket(pb.T)
     S2, H2 = _bucket(pb.S), _bucket(pb.H)

@@ -77,8 +77,6 @@ def run_cell(arch: str, shape: str, mesh_kind: str, mode: str,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):      # jax 0.4.x returns [dict]
-        cost = cost[0] if cost else {}
     rec.update(
         lower_s=round(t_lower, 1), compile_s=round(t_compile, 1),
         flops=float(cost.get("flops", 0.0)),

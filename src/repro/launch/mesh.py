@@ -1,26 +1,18 @@
-"""Production mesh construction (spec-mandated shapes).
+"""Mesh construction.
 
-``make_production_mesh`` is a FUNCTION so importing this module never
-touches jax device state."""
+These are FUNCTIONS so importing this module never touches jax device
+state."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
-def make_mesh(shape, axes):
-    """``jax.make_mesh`` across jax versions: newer releases expose
-    ``jax.sharding.AxisType`` and accept ``axis_types``; older ones (e.g.
-    0.4.x) default every axis to Auto and take no such argument.  Both
-    paths produce an all-Auto mesh."""
-    try:
-        from jax.sharding import AxisType
-    except ImportError:
-        return jax.make_mesh(shape, axes)
-    try:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    except TypeError:  # pragma: no cover - AxisType without the kwarg
-        return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes, *, devices=None):
+    """An all-Auto ``jax.make_mesh`` (GSPMD places what the code does not
+    constrain), over ``devices`` when given."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

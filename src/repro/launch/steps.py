@@ -250,7 +250,7 @@ def build_tapa_train(cfg: ArchConfig, mesh: Mesh, cell: ShapeCell, *,
     n_micro = n_micro or n_micro_for(cfg)
     mesh_shape = tuple(mesh.devices.shape)
     if plan is None:
-        plan = plan_cell(cfg, cell.name, mesh_shape, seed=seed, mode="tapa")
+        plan = plan_cell(cfg, cell, mesh_shape, seed=seed, mode="tapa")
     rmesh = refined_mesh(mesh, plan)
     opt_init, opt_update = _opt_fns(cfg)
     loss_fn = pp.build_train_loss(cfg, plan, rmesh, n_micro=n_micro,

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import configs
+from repro import compile_cache, configs
 from repro.ckpt import latest_step, restore_checkpoint, save_checkpoint
 from repro.data import ShardedLoader, SyntheticTokens
 from repro.model import lm
@@ -44,6 +44,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    compile_cache.configure()
     cfg = configs.get_reduced(args.arch) if args.reduced \
         else configs.get(args.arch)
     print(f"train: {cfg.name} params~{cfg.param_count()/1e6:.1f}M "

@@ -1,8 +1,8 @@
 """Core layers (pure functional JAX; params are plain pytrees).
 
 Everything is bf16 by default with fp32 norms/softmax internals.  The
-attention / SSM / MoE hot spots route through ``repro.kernels.ops`` so the
-Pallas TPU kernels and the jnp references are interchangeable.
+attention / SSM hot spots route through ``repro.kernels.ops``; ``kernel``
+picks the Pallas TPU kernel over the XLA reference (see ``lm.step``).
 """
 from __future__ import annotations
 
@@ -114,7 +114,7 @@ class AttnSpec:
 
 
 def attn_apply(p, cfg: ArchConfig, spec: AttnSpec, x, *, positions,
-               cache=None, kv_from=None, kv_len=None):
+               cache=None, kv_from=None, kv_len=None, kernel=False):
     """x: (B, S, d).  cache: optional dict(k, v, pos) for decode.
     kv_from: cross-attention memory (B, Sm, d) — overrides self-KV."""
     B, S, _ = x.shape
@@ -136,7 +136,7 @@ def attn_apply(p, cfg: ArchConfig, spec: AttnSpec, x, *, positions,
         # prefill from scratch (pos assumed 0): full attention, then store
         # the last W tokens ring-aligned (token t lives at slot t % W)
         out = ops.attention(q, k, v, causal=spec.causal, window=spec.window,
-                            softcap=spec.softcap, scale=scale)
+                            softcap=spec.softcap, scale=scale, kernel=kernel)
         ck, cv = cache["k"], cache["v"]
         W = ck.shape[1]
         if S >= W:
@@ -165,18 +165,19 @@ def attn_apply(p, cfg: ArchConfig, spec: AttnSpec, x, *, positions,
         if spec.window is None:
             kv_len = jnp.full((B,), pos + S) if kv_len is None else kv_len
             out = ops.attention(q, k, v, causal=False, softcap=spec.softcap,
-                                scale=scale, q_offset=pos, kv_len=kv_len)
+                                scale=scale, q_offset=pos, kv_len=kv_len,
+                                kernel=kernel)
         else:
             # ring buffer: valid entries = min(pos + S, W); no causal mask
             # needed (all cached tokens precede the query)
             valid = jnp.minimum(pos + S, W)
             out = ops.attention(q, k, v, causal=False, softcap=spec.softcap,
-                                scale=scale,
-                                kv_len=jnp.full((B,), valid))
+                                scale=scale, kv_len=jnp.full((B,), valid),
+                                kernel=kernel)
     else:
         out = ops.attention(q, k, v, causal=spec.causal and kv_from is None,
                             window=spec.window, softcap=spec.softcap,
-                            scale=scale, kv_len=kv_len)
+                            scale=scale, kv_len=kv_len, kernel=kernel)
     y = out.reshape(B, S, H * D) @ p["wo"]
     return y, cache
 

@@ -119,8 +119,9 @@ def init_params(cfg: ArchConfig, key) -> dict:
         return [_block_init(cfg, ch, kk[i])
                 for i, ch in enumerate(cfg.layer_pattern)]
 
-    groups = [one_group(k) for k in group_keys]
-    params["groups"] = jax.tree.map(lambda *xs: jnp.stack(xs), *groups)
+    # vmapped over the groups' keys: the same draws as one call per key,
+    # stacked as they are made (a loop would trace n_groups copies)
+    params["groups"] = jax.vmap(one_group)(group_keys)
 
     if "H" in cfg.layer_pattern:
         # zamba2: two shared attention+mlp blocks, alternated
@@ -155,20 +156,21 @@ def _ffn(p, cfg: ArchConfig, h):
 
 def _block_apply(p, cfg: ArchConfig, kind: str, spec: AttnSpec, x, *,
                  positions, x0=None, memory=None, cache=None, shared=None,
-                 shared_idx=0):
-    """One layer.  Returns (x, aux, new_cache)."""
+                 shared_idx=0, kernel=False):
+    """One layer.  Returns (x, aux, new_cache).  ``kernel``: run the Pallas
+    kernels (serving on a TPU only — they have no VJP)."""
     aux = 0.0
     if kind in ("G", "L", "X"):
         h = rmsnorm(x, p["ln_attn"])
         a, cache = attn_apply(p["attn"], cfg, spec, h, positions=positions,
-                              cache=cache)
+                              cache=cache, kernel=kernel)
         if cfg.post_norms:
             a = rmsnorm(a, p["ln_attn_post"])
         x = x + a
         if kind == "X" and memory is not None:
             h = rmsnorm(x, p["ln_xattn"])
             xa, _ = attn_apply(p["xattn"], cfg, spec, h, positions=positions,
-                               kv_from=memory)
+                               kv_from=memory, kernel=kernel)
             x = x + jnp.tanh(p["xattn_gate"]).astype(x.dtype) * xa
         h = rmsnorm(x, p["ln_mlp"])
         f, aux = _ffn(p, cfg, h)
@@ -177,12 +179,13 @@ def _block_apply(p, cfg: ArchConfig, kind: str, spec: AttnSpec, x, *,
         x = x + f
     elif kind == "M":
         h = rmsnorm(x, p["ln"])
-        y, cache = m2.mamba2_apply(p["mamba"], cfg, h, cache)
+        y, cache = m2.mamba2_apply(p["mamba"], cfg, h, cache, kernel=kernel)
         x = x + y
     elif kind == "H":
         h = rmsnorm(x, p["ln"])
         mcache = cache["mamba"] if cache is not None else None
-        y, mcache = m2.mamba2_apply(p["mamba"], cfg, h, mcache)
+        y, mcache = m2.mamba2_apply(p["mamba"], cfg, h, mcache,
+                                    kernel=kernel)
         x = x + y
         # shared attention block over concat(hidden, initial embeddings) —
         # the zamba2 skip stream (a reconvergent path in the task graph)
@@ -191,7 +194,8 @@ def _block_apply(p, cfg: ArchConfig, kind: str, spec: AttnSpec, x, *,
         hin = jnp.concatenate([x, x0], axis=-1)
         hin = rmsnorm(hin, p["ln_shared_in"]) @ p["w_shared_in"]
         a, acache = attn_apply(sb["attn"], cfg, spec, hin,
-                               positions=positions, cache=acache)
+                               positions=positions, cache=acache,
+                               kernel=kernel)
         a = a + mlp_apply(sb["mlp"], cfg, rmsnorm(a, sb["ln_mlp"]))
         x = x + a @ p["w_shared_out"]
         if cache is not None:
@@ -204,7 +208,7 @@ def _block_apply(p, cfg: ArchConfig, kind: str, spec: AttnSpec, x, *,
         wkv = cache["wkv"] if cache is not None else None
         h = rmsnorm(x, p["ln_tm"])
         y, new_tm, wkv = r6.time_mix_apply(p["rwkv"]["time_mix"], cfg, h,
-                                           tm_shift, wkv)
+                                           tm_shift, wkv, kernel=kernel)
         x = x + y
         h = rmsnorm(x, p["ln_cm"])
         y, new_cm = r6.chan_mix_apply(p["rwkv"]["chan_mix"], cfg, h, cm_shift)
@@ -219,9 +223,9 @@ def _block_apply(p, cfg: ArchConfig, kind: str, spec: AttnSpec, x, *,
 # forward passes
 # ---------------------------------------------------------------------------
 
-def _embed(params, cfg: ArchConfig, tokens):
+def _embed(params, cfg: ArchConfig, tokens, *, kernel=False):
     B, S = tokens.shape
-    x = ops.burst_gather(params["embed"], tokens.reshape(-1))
+    x = ops.burst_gather(params["embed"], tokens.reshape(-1), kernel=kernel)
     x = x.reshape(B, S, cfg.d_model)
     if cfg.name.startswith("gemma"):
         x = x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
@@ -366,19 +370,23 @@ def loss_fn(params, cfg: ArchConfig, batch, *, remat: bool = False):
 # ---------------------------------------------------------------------------
 
 def init_cache(params, cfg: ArchConfig, batch, max_seq, extra=None):
+    """Empty caches, holding activations in the parameters' dtype (the
+    embedding's), so a float32 model keeps float32 keys and values."""
     specs = build_specs(cfg)
     n_groups = cfg.n_layers // len(cfg.layer_pattern)
+    dtype = params["embed"].dtype
 
     def one(spec, ch):
         if ch in ("G", "L", "X"):
-            return attn_cache_init(cfg, spec, batch, max_seq)
+            return attn_cache_init(cfg, spec, batch, max_seq, dtype)
         if ch == "M":
-            return m2.mamba2_cache_init(cfg, batch)
+            return m2.mamba2_cache_init(cfg, batch, dtype)
         if ch == "H":
-            return {"mamba": m2.mamba2_cache_init(cfg, batch),
-                    "attn": attn_cache_init(cfg, specs[0], batch, max_seq)}
+            return {"mamba": m2.mamba2_cache_init(cfg, batch, dtype),
+                    "attn": attn_cache_init(cfg, specs[0], batch, max_seq,
+                                            dtype)}
         if ch == "R":
-            return r6.rwkv6_cache_init(cfg, batch)
+            return r6.rwkv6_cache_init(cfg, batch, dtype)
         raise ValueError(ch)
 
     group_cache = [one(specs[i], ch)
@@ -392,10 +400,19 @@ def init_cache(params, cfg: ArchConfig, batch, max_seq, extra=None):
     return {"groups": stacked, "pos": jnp.zeros((), jnp.int32), **mem}
 
 
+def kernels_here() -> bool:
+    """Whether ``step`` runs the Pallas kernels: on a TPU, yes.  Elsewhere,
+    and on every differentiated path, the model runs the XLA references
+    (``repro.kernels.ops``)."""
+    return jax.default_backend() == "tpu"
+
+
 def step(params, cfg: ArchConfig, cache, tokens, *, unroll: bool = False):
-    """Prefill (S>=1) or decode (S=1) step -> (logits_last, new_cache)."""
+    """Prefill (S>=1) or decode (S=1) step -> (logits_last, new_cache).
+    Runs the Pallas kernels where ``kernels_here()``."""
+    kernel = kernels_here()
     specs = build_specs(cfg)
-    x = _embed(params, cfg, tokens)
+    x = _embed(params, cfg, tokens, kernel=kernel)
     S = tokens.shape[1]
     pos0 = cache["pos"]
     positions = pos0 + jnp.arange(S)
@@ -413,7 +430,7 @@ def step(params, cfg: ArchConfig, cache, tokens, *, unroll: bool = False):
             x, a, ci = _block_apply(gp[i], cfg, ch, specs[i], x,
                                     positions=positions, x0=x0,
                                     memory=memory, cache=ci, shared=shared,
-                                    shared_idx=h_idx % 2)
+                                    shared_idx=h_idx % 2, kernel=kernel)
             if ch == "H":
                 h_idx += 1
             new_gc.append(ci)

@@ -44,7 +44,7 @@ def _causal_conv(x, w, state=None):
     return out, new_state
 
 
-def mamba2_apply(p, cfg: ArchConfig, x, cache=None):
+def mamba2_apply(p, cfg: ArchConfig, x, cache=None, *, kernel=False):
     """x: (B, S, d).  cache: {"conv": (B,K-1,C), "ssd": (B,H,P,N), "pos"}."""
     B, S, d = x.shape
     d_in = cfg.ssm_expand * d
@@ -65,7 +65,8 @@ def mamba2_apply(p, cfg: ArchConfig, x, cache=None):
     A = -jnp.exp(p["A_log"])
     xh = xin.reshape(B, S, H, P)
     ssd_state = cache["ssd"] if cache is not None else None
-    y, new_ssd = ops.mamba2_scan(xh, dtp, A, Bc, Cc, ssd_state)
+    y, new_ssd = ops.mamba2_scan(xh, dtp, A, Bc, Cc, ssd_state,
+                                 kernel=kernel)
     y = y + xh * p["D"][None, None, :, None].astype(y.dtype)
     y = y.reshape(B, S, d_in)
     y = rmsnorm(y, p["norm"]) * jax.nn.silu(z)

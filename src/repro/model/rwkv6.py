@@ -48,7 +48,7 @@ def _token_shift(x, last):
     return jnp.concatenate([last, x[:, :-1]], axis=1)
 
 
-def time_mix_apply(p, cfg: ArchConfig, x, shift, state):
+def time_mix_apply(p, cfg: ArchConfig, x, shift, state, *, kernel=False):
     """x: (B,S,d); shift: (B,1,d) last token of previous chunk;
     state: (B,H,D,D) WKV state.  Returns y, new_shift, new_state."""
     B, S, d = x.shape
@@ -65,7 +65,8 @@ def time_mix_apply(p, cfg: ArchConfig, x, shift, state):
         jnp.tanh(mix(4).astype(jnp.float32) @ p["w_A"].astype(jnp.float32)) \
         @ p["w_B"].astype(jnp.float32)
     w = jnp.exp(-jnp.exp(w_raw)).reshape(B, S, H, D)         # decay in (0,1)
-    y, new_state = ops.rwkv6_scan(r, k, v, w.astype(r.dtype), p["u"], state)
+    y, new_state = ops.rwkv6_scan(r, k, v, w.astype(r.dtype), p["u"], state,
+                                  kernel=kernel)
     y = y.reshape(B, S, d)
     y = rmsnorm(y, p["ln_x"]) * g
     return y @ p["wo"], x[:, -1:], new_state

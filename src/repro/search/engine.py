@@ -402,29 +402,26 @@ def measure_backend_speedup(jobs: Sequence[SimJob], *,
     """Measured NumPy-vs-jax wall time on one job list (the BENCH JSON
     ``sim.speedup`` block): times the padded NumPy sweep, then the jitted
     sweep with its compilation warmed up outside the timed window (the
-    compile cost is reported separately as ``jax_compile_s``).  When jax
-    is unavailable the jax fields are None and ``speedup`` is None — the
-    figure is *measured*, never asserted.  Engine counters are restored
+    compile cost is reported separately as ``jax_compile_s``).  The figure
+    is *measured*, never asserted.  Engine counters are restored
     afterwards so gates on the main call's counts stay unpolluted."""
-    from repro.core.simulate import _ENGINE_INVOCATIONS, _jax_ready
+    from repro.core.simulate import _ENGINE_INVOCATIONS
     jobs = list(jobs)
     saved = dict(_ENGINE_INVOCATIONS)
     try:
         t0 = time.monotonic()
         simulate_batch(jobs, firings=firings, backend="numpy")
         numpy_wall = time.monotonic() - t0
-        out = {"jobs": len(jobs), "firings": firings,
-               "numpy_wall_s": numpy_wall, "jax_compile_s": None,
-               "jax_wall_s": None, "speedup": None}
-        if _jax_ready():
-            t0 = time.monotonic()
-            simulate_batch(jobs, firings=firings, backend="jax")  # warm-up
-            out["jax_compile_s"] = time.monotonic() - t0
-            t0 = time.monotonic()
-            simulate_batch(jobs, firings=firings, backend="jax")
-            out["jax_wall_s"] = time.monotonic() - t0
-            out["speedup"] = numpy_wall / max(out["jax_wall_s"], 1e-9)
-        return out
+        t0 = time.monotonic()
+        simulate_batch(jobs, firings=firings, backend="jax")  # warm-up
+        jax_compile = time.monotonic() - t0
+        t0 = time.monotonic()
+        simulate_batch(jobs, firings=firings, backend="jax")
+        jax_wall = time.monotonic() - t0
+        return {"jobs": len(jobs), "firings": firings,
+                "numpy_wall_s": numpy_wall, "jax_compile_s": jax_compile,
+                "jax_wall_s": jax_wall,
+                "speedup": numpy_wall / max(jax_wall, 1e-9)}
     finally:
         _ENGINE_INVOCATIONS.clear()
         _ENGINE_INVOCATIONS.update(saved)

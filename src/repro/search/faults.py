@@ -32,25 +32,22 @@ exactly the failure the pool's retry machinery must absorb.  Set
 ``attempts`` high to model a *poison* input that kills every worker it
 touches — the pool must quarantine it, not retry forever.
 
-Plans propagate to subprocesses via the ``REPRO_FAULTS`` environment
-variable (a JSON dict of plan fields), so spawn-context pool workers and
-benchmark child processes observe the same schedule as the parent.
-``install()`` sets both the in-process plan and the env var.
+``install()`` activates a plan in this process.  Pool workers receive the
+parent's plan as an argument of every solve, and a benchmark child
+process on its command line (``fmax_suite.py --faults JSON``), so every
+process observes the same schedule; nothing travels through the
+environment.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
-import json
 import os
 import random
 import signal
 import time
 
 from ..obs import metrics as _metrics
-
-#: env var carrying a JSON-encoded plan to subprocesses
-ENV_VAR = "REPRO_FAULTS"
 
 #: fault sites whose rate is a plan field
 _RATE_SITES = ("worker_crash", "worker_hang", "torn_write")
@@ -95,6 +92,10 @@ class FaultPlan:
     attempts: int = 1
     #: sleep length of an injected hang (set well above the pool timeout)
     hang_s: float = 30.0
+    #: per-future deadline the pool uses while this plan is active (None:
+    #: the pool's default); a drill with injected hangs sets it short so
+    #: the hangs resolve in seconds
+    pool_timeout_s: float | None = None
 
     def decide(self, site: str, token: str, attempt: int = 0) -> bool:
         """Pure seeded decision: does ``site`` fault for ``token`` on this
@@ -120,41 +121,20 @@ _PLAN: FaultPlan | None = None
 
 
 def active_plan() -> FaultPlan | None:
-    """The installed plan, else one parsed from ``REPRO_FAULTS`` (how
-    spawn-context workers and benchmark children inherit the schedule)."""
-    if _PLAN is not None:
-        return _PLAN
-    raw = os.environ.get(ENV_VAR)
-    if not raw:
-        return None
-    try:
-        return FaultPlan.from_dict(json.loads(raw))
-    except (ValueError, TypeError):
-        return None
+    """The plan installed in this process, if any."""
+    return _PLAN
 
 
 @contextlib.contextmanager
-def install(plan: FaultPlan | None, *, env: bool = True):
-    """Activate ``plan`` for the enclosed block (and, with ``env=True``,
-    for subprocesses started inside it).  ``install(None)`` masks any
-    ambient ``REPRO_FAULTS`` so a block provably runs clean."""
+def install(plan: FaultPlan | None):
+    """Activate ``plan`` for the enclosed block; ``install(None)`` masks
+    an outer plan so a block provably runs clean."""
     global _PLAN
-    prev_plan, prev_env = _PLAN, os.environ.get(ENV_VAR)
-    _PLAN = plan
-    if env:
-        if plan is None:
-            os.environ.pop(ENV_VAR, None)
-        else:
-            os.environ[ENV_VAR] = json.dumps(plan.as_dict())
+    prev_plan, _PLAN = _PLAN, plan
     try:
         yield plan
     finally:
         _PLAN = prev_plan
-        if env:
-            if prev_env is None:
-                os.environ.pop(ENV_VAR, None)
-            else:
-                os.environ[ENV_VAR] = prev_env
 
 
 def fire(site: str, token: str, attempt: int = 0) -> bool:

@@ -41,9 +41,6 @@ replay sees a verdict instead of re-crashing forever.  Exceptions raised
 *by the solve itself* (other than ``InfeasibleError``, handled in-worker)
 still propagate: retrying can only mask a real bug.
 
-``REPRO_POOL_CTX`` forces the multiprocessing start method (CI runs one
-pool leg under ``spawn``); ``REPRO_POOL_TIMEOUT_S`` / ``REPRO_POOL_RETRIES``
-override the per-future deadline and retry budget without code changes.
 The ``repro.search.faults`` harness injects deterministic worker crashes
 and hangs through this module's worker entry point.
 """
@@ -84,10 +81,9 @@ _POOL_COUNTS = _metrics.group(
      "pool_rebuilds": 0})
 
 #: default per-future deadline before a point's workers are killed and the
-#: point re-dispatched (override: ``REPRO_POOL_TIMEOUT_S`` or the
-#: ``timeout_s=`` parameter)
+#: point re-dispatched (the ``timeout_s=`` parameter)
 DEFAULT_TIMEOUT_S = 120.0
-#: default re-dispatch budget per point for timeouts (``REPRO_POOL_RETRIES``)
+#: default re-dispatch budget per point for timeouts (``max_retries=``)
 DEFAULT_RETRIES = 3
 #: worker crashes a single point may be implicated in before quarantine
 DEFAULT_CRASH_LIMIT = 3
@@ -187,6 +183,7 @@ def _solve_point(graph: TaskGraph, grid: SlotGrid, pt_kwargs: dict,
                  ab_kwargs: dict, token: str = "", attempt: int = 0,
                  marker_dir: str | None = None, trace_token: str = "",
                  trace_on: bool = False,
+                 plan: faults.FaultPlan | None = None,
                  ) -> tuple[FloorplanCache, dict, list, str | None]:
     """Worker entry point (module-level so it pickles by reference).
 
@@ -202,14 +199,16 @@ def _solve_point(graph: TaskGraph, grid: SlotGrid, pt_kwargs: dict,
     ``marker_dir`` receives a started-marker file per attempt before any
     work (or injected fault) happens: when a crash breaks the pool, the
     parent charges the crash only to points whose marker exists — points
-    still queued are re-dispatched blame-free."""
+    still queued are re-dispatched blame-free.  ``plan`` is the parent's
+    fault plan (workers of any start method see the same schedule)."""
     if marker_dir:
         with open(os.path.join(marker_dir,
                                f"{_marker_name(token)}.{attempt}"), "w"):
             pass
     _trace.begin_worker(trace_token, enable_tracing=trace_on)
-    faults.fire("worker_hang", token, attempt)
-    faults.fire("worker_crash", token, attempt)
+    with faults.install(plan):
+        faults.fire("worker_hang", token, attempt)
+        faults.fire("worker_crash", token, attempt)
     before = _metrics.snapshot()
     cache = FloorplanCache()
     err = None
@@ -228,9 +227,6 @@ def _marker_name(token: str) -> str:
 
 def _mp_context():
     """Prefer fork (POSIX); fall back to spawn where fork is unavailable.
-    ``REPRO_POOL_CTX`` forces a specific start method (the tier-1 CI
-    matrix runs one pool leg under ``REPRO_POOL_CTX=spawn`` so the
-    fallback path stays tested instead of vestigial).
 
     Fork is the only start method that works for unguarded caller scripts
     (``examples/quickstart.py``-style: no ``if __name__ == "__main__"``)
@@ -241,13 +237,6 @@ def _mp_context():
     while these workers only run the pure-Python/NumPy solve chain and
     never touch jax — the configuration the whole tier-1 suite exercises."""
     methods = multiprocessing.get_all_start_methods()
-    override = os.environ.get("REPRO_POOL_CTX")
-    if override:
-        if override not in methods:
-            raise ValueError(
-                f"REPRO_POOL_CTX={override!r} is not an available start "
-                f"method (have: {', '.join(methods)})")
-        return multiprocessing.get_context(override)
     return multiprocessing.get_context(
         "fork" if "fork" in methods else "spawn")
 
@@ -313,13 +302,6 @@ def _hard_shutdown(ex: concurrent.futures.ProcessPoolExecutor) -> None:
         ex.shutdown(wait=True, cancel_futures=True)
     except Exception:
         pass
-
-
-def _env_float(name: str, default: float) -> float:
-    try:
-        return float(os.environ[name])
-    except (KeyError, ValueError):
-        return default
 
 
 @dataclasses.dataclass
@@ -390,13 +372,13 @@ def warm_floorplan_cache(graph: TaskGraph, grid: SlotGrid,
             stats.static_skipped = len(todo)
             _POOL_COUNTS["static_skipped"] += len(todo)
             return stats
+    plan = faults.active_plan()
     if timeout_s is None:
-        timeout_s = _env_float("REPRO_POOL_TIMEOUT_S", DEFAULT_TIMEOUT_S)
+        timeout_s = ((plan and plan.pool_timeout_s) or DEFAULT_TIMEOUT_S)
     if max_retries is None:
-        max_retries = int(_env_float("REPRO_POOL_RETRIES", DEFAULT_RETRIES))
+        max_retries = DEFAULT_RETRIES
     if crash_limit is None:
         crash_limit = DEFAULT_CRASH_LIMIT
-    plan = faults.active_plan()
 
     t0 = time.monotonic()
     _span = _trace.begin("pool.warm", jobs=jobs, points=len(todo))
@@ -425,7 +407,7 @@ def warm_floorplan_cache(graph: TaskGraph, grid: SlotGrid,
                     faults.count_injected(site)
         fut = ex.submit(_solve_point, graph, grid, _point_kwargs(task.pt),
                         ab_kwargs, task.token, task.dispatches, marker_dir,
-                        _trace.current_token(), _trace.enabled())
+                        _trace.current_token(), _trace.enabled(), plan)
         if task.dispatches > 0:
             stats.retried += 1
         task.dispatches += 1

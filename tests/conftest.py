@@ -1,12 +1,6 @@
 """Test-suite bootstrap: make the tests directory importable so modules can
 use the `_propcheck` hypothesis-compat shim regardless of pytest import
-mode, and make `src/` importable even without PYTHONPATH=src.
-
-Also skips the jax-only test modules (kernels, models, training substrate,
-distributed launch) when jax is not installed — the CI no-jax tier-1 leg
-runs the whole dataflow/search/simulator suite without them, proving the
-core never needs jax and that ``backend="auto"`` degrades cleanly."""
-import importlib.util
+mode, and make `src/` importable even without PYTHONPATH=src."""
 import os
 import sys
 
@@ -15,20 +9,6 @@ _SRC = os.path.join(os.path.dirname(_HERE), "src")
 for p in (_HERE, _SRC):
     if p not in sys.path:
         sys.path.insert(0, p)
-
-#: modules that import jax (directly or through repro.model/launch) at
-#: collection time; everything else must collect and pass without jax
-_JAX_ONLY = [
-    "test_distributed.py",
-    "test_dryrun_small.py",
-    "test_kernels.py",
-    "test_models_smoke.py",
-    "test_substrate.py",
-]
-
-collect_ignore = (
-    [] if importlib.util.find_spec("jax") is not None else list(_JAX_ONLY)
-)
 
 import pytest  # noqa: E402  (after the sys.path bootstrap above)
 

@@ -8,6 +8,9 @@ import textwrap
 
 import pytest
 
+#: the checkout root, wherever it was copied to
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 SCRIPT = textwrap.dedent("""
     import os
     import jax, jax.numpy as jnp
@@ -68,7 +71,7 @@ def test_dryrun_small_8dev():
     env = dict(os.environ, PYTHONPATH="src",
                XLA_FLAGS="--xla_force_host_platform_device_count=8")
     r = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
-                       cwd="/root/repo", capture_output=True, text=True,
+                       cwd=ROOT, capture_output=True, text=True,
                        timeout=2400)
     assert r.returncode == 0, r.stderr[-4000:]
     assert "DRYRUN_SMALL_OK" in r.stdout

@@ -168,6 +168,24 @@ def test_burst_gather_property(seed, n, seq_frac):
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("pattern", ["aligned", "shifted", "random"])
+def test_burst_gather_paths(dtype, pattern):
+    """Both DMA paths, on a table whose rows and width are not tile
+    multiples: tile-aligned runs take the one-DMA burst path, shifted runs
+    and random rows the per-row path (which selects a row out of its 8-row
+    HBM tile).  bf16 packs two rows per word, so row selection must not
+    straddle a packed pair."""
+    rng = np.random.default_rng(7)
+    table = jnp.asarray(rng.normal(size=(101, 40)), dtype)
+    idx = {"aligned": np.arange(16, 48),
+           "shifted": np.arange(3, 35),
+           "random": rng.integers(0, 101, 32)}[pattern]
+    got = burst_gather(table, jnp.asarray(idx, jnp.int32), interpret=True)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(table, np.float32)[idx])
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("shape", [(50, 24, 36, 5), (16, 8, 8, 2),
                                    (130, 64, 32, 8)])
 def test_moe_gmm_sweep(dtype, shape):

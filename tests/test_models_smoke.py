@@ -100,3 +100,53 @@ def test_sliding_window_ring_cache_consistency():
     np.testing.assert_allclose(
         np.asarray(full_logits, np.float32), np.asarray(dec, np.float32),
         rtol=0.06, atol=0.06)
+
+
+def test_serve_generate_matches_forward():
+    """The serving entry point: prefill, then greedy decode through
+    the donated cache; every step's last-position logits match the full
+    forward pass over the same tokens, and each token is the argmax of the
+    logits before it."""
+    from repro.launch import serve
+
+    cfg = configs.get_reduced("granite-moe-3b-a800m")
+    params = serve.init_params(cfg, 0)
+    B, S, gen = 2, 16, 4
+    prompts = jax.random.randint(jax.random.PRNGKey(5), (B, S), 0, cfg.vocab)
+    out = serve.generate(params, cfg, prompts, gen=gen)
+    assert out.tokens.shape == (B, gen) and len(out.logits) == gen + 1
+    for i in range(gen):
+        np.testing.assert_array_equal(
+            np.asarray(out.tokens[:, i]),
+            np.asarray(jnp.argmax(out.logits[i], -1)))
+    seq = jnp.concatenate([prompts, out.tokens], axis=1)
+    full, _ = lm.forward(params, cfg, seq)
+    for i, lg in enumerate(out.logits):
+        np.testing.assert_allclose(
+            np.asarray(lg[:, :cfg.vocab], np.float32),
+            np.asarray(full[:, S - 1 + i], np.float32), rtol=0.05, atol=0.05)
+
+
+def test_kernels_only_in_the_serving_step_on_tpu(monkeypatch):
+    """One path per op: the serving step runs the Pallas kernels where the
+    platform is a TPU; off a TPU, and on the differentiated path always,
+    the model runs the XLA references."""
+    cfg = configs.get_reduced("granite-moe-3b-a800m")
+    params = lm.init_params(cfg, jax.random.PRNGKey(0))
+    cache = lm.init_cache(params, cfg, 2, max_seq=32)
+    toks = jnp.zeros((2, 16), jnp.int32)
+
+    def kernels_in_step():     # a fresh function: no cached trace
+        return str(jax.make_jaxpr(lambda p, c, t: lm.step(p, cfg, c, t))(
+            params, cache, toks)).count("pallas_call")
+
+    def kernels_in_grad():
+        return str(jax.make_jaxpr(lambda p: jax.grad(
+            lambda q: lm.loss_fn(q, cfg, {"tokens": toks}))(p))(
+            params)).count("pallas_call")
+
+    assert not lm.kernels_here()            # the tests run on the CPU
+    assert kernels_in_step() == 0
+    monkeypatch.setattr(lm, "kernels_here", lambda: True)
+    assert kernels_in_step() > 0
+    assert kernels_in_grad() == 0

@@ -20,16 +20,11 @@ sweep's padded ``fired`` array itself (phantom columns must stay 0).
 import random
 
 import numpy as np
-import pytest
 from _propcheck import given, settings, strategies as st
 
 from repro.core import SimJob, simulate_batch
-from repro.core.simulate import _jax_ready
 from repro.corpus import random_graph as _random_graph
 from repro.kernels.padded_batch import build_padded_batch
-
-jax_only = pytest.mark.skipif(not _jax_ready(), reason="jax not installed")
-
 
 def _mixed_jobs(seed: int) -> list:
     """2-6 jobs over independently random topologies (cycles, detached
@@ -104,7 +99,6 @@ def test_phantom_streams_never_stall_padded_equals_solo(seed):
         assert got.deadlocked == solo.deadlocked
 
 
-@jax_only
 @settings(max_examples=15, deadline=None)
 @given(st.integers(0, 99_999))
 def test_phantom_tasks_never_fire(seed):

@@ -3,8 +3,8 @@ harness, the hardened worker pool, and checkpointed ``search_until_converged``.
 
 Covers: ``FaultPlan`` purity and env-var propagation, pool survival of
 injected worker crashes and hangs with the frontier bit-identical to a
-clean run, poison-point quarantine as a cached verdict, the
-``REPRO_POOL_CTX`` start-method override, kill-between-rounds resume
+clean run, poison-point quarantine as a cached verdict, the pool's
+start method (and its results under spawn), kill-between-rounds resume
 (a real SIGKILLed subprocess) reproducing the uninterrupted frontier,
 completed-checkpoint replay without re-solving, torn-store-write
 transparency, and the checkpoint config fingerprint refusing foreign
@@ -100,33 +100,35 @@ def test_fault_plan_kill_site_matches_round_token():
     assert not FaultPlan().decide("parent_kill", "2")
 
 
-def test_fault_plan_env_roundtrip(monkeypatch):
-    plan = FaultPlan(seed=9, torn_write=0.25, kill_after_round=1)
-    assert FaultPlan.from_dict(plan.as_dict()) == plan
+def test_fault_plan_env_roundtrip():
+    """A plan travels as JSON (a child's command line), and installing it
+    touches this process only: nothing goes through the environment."""
+    plan = FaultPlan(seed=9, torn_write=0.25, kill_after_round=1,
+                     pool_timeout_s=2.0)
+    assert FaultPlan.from_dict(json.loads(json.dumps(plan.as_dict()))) \
+        == plan
     # unknown keys from a newer writer are ignored, not fatal
     assert FaultPlan.from_dict(
         dict(plan.as_dict(), future_knob=1)) == plan
-    monkeypatch.delenv(faults.ENV_VAR, raising=False)
+    env = dict(os.environ)
     with install_faults(plan):
-        assert json.loads(os.environ[faults.ENV_VAR]) == plan.as_dict()
         assert faults.active_plan() == plan
-    assert faults.ENV_VAR not in os.environ
+        assert dict(os.environ) == env
     assert faults.active_plan() is None
 
 
-def test_install_none_masks_ambient_env_plan(monkeypatch):
-    monkeypatch.setenv(faults.ENV_VAR,
-                       json.dumps(FaultPlan(torn_write=1.0).as_dict()))
-    assert faults.active_plan() is not None
-    with install_faults(None):
-        assert faults.active_plan() is None
-    assert faults.active_plan() is not None
+def test_install_none_masks_ambient_env_plan():
+    with install_faults(FaultPlan(torn_write=1.0)):
+        assert faults.active_plan() is not None
+        with install_faults(None):
+            assert faults.active_plan() is None
+        assert faults.active_plan() is not None
 
 
 def test_fire_counts_and_returns_for_torn_write():
-    with install_faults(FaultPlan(torn_write=1.0), env=False):
+    with install_faults(FaultPlan(torn_write=1.0)):
         assert faults.fire("torn_write", "any-token") is True
-    with install_faults(FaultPlan(torn_write=0.0), env=False):
+    with install_faults(FaultPlan(torn_write=0.0)):
         assert faults.fire("torn_write", "any-token") is False
     assert fault_counts()["torn_write"] == 1
 
@@ -208,29 +210,37 @@ def test_injected_faults_never_change_the_converged_frontier(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# REPRO_POOL_CTX override
+# pool start method
 # ---------------------------------------------------------------------------
 
 
 def test_pool_ctx_env_override(monkeypatch):
-    monkeypatch.setenv("REPRO_POOL_CTX", "spawn")
-    assert _mp_context().get_start_method() == "spawn"
-    monkeypatch.setenv("REPRO_POOL_CTX", "not-a-method")
-    with pytest.raises(ValueError, match="REPRO_POOL_CTX"):
-        _mp_context()
-    monkeypatch.delenv("REPRO_POOL_CTX")
-    assert _mp_context().get_start_method() in ("fork", "spawn")
+    """No environment variable picks the start method: fork where the
+    platform has it (unguarded caller scripts work), spawn elsewhere."""
+    import multiprocessing
+
+    monkeypatch.setenv("REPRO_POOL_CTX", "spawn")     # ignored
+    want = ("fork" if "fork" in multiprocessing.get_all_start_methods()
+            else "spawn")
+    assert _mp_context().get_start_method() == want
 
 
 def test_pool_solves_under_spawn_context(monkeypatch):
-    monkeypatch.setenv("REPRO_POOL_CTX", "spawn")
+    """Spawned workers inherit nothing, so everything a solve needs must
+    travel as arguments; the merged cache must equal the default pool's."""
+    import multiprocessing
+
+    from repro.search import pool
+
+    monkeypatch.setattr(pool, "_mp_context",
+                        lambda: multiprocessing.get_context("spawn"))
     cache = FloorplanCache()
     stats = warm_floorplan_cache(_chain_graph(), GRID, POINTS[:2],
                                  cache=cache, jobs=2)
     assert stats.merged == stats.dispatched == 2
+    monkeypatch.undo()
     ref = FloorplanCache()
     warm_floorplan_cache(_chain_graph(), GRID, POINTS[:2], cache=ref, jobs=2)
-    monkeypatch.delenv("REPRO_POOL_CTX")
     assert set(cache._entries) == set(ref._entries)
 
 
@@ -239,9 +249,10 @@ def test_pool_solves_under_spawn_context(monkeypatch):
 # ---------------------------------------------------------------------------
 
 _CHILD = textwrap.dedent("""
-    import sys
+    import json, sys
     from repro.core import SearchSpace, SlotGrid, TaskGraphBuilder
-    from repro.search import search_until_converged
+    from repro.search import (FaultPlan, install_faults,
+                              search_until_converged)
 
     def chain(n=4, width=64, lut=100):
         b = TaskGraphBuilder("chain")
@@ -255,22 +266,21 @@ _CHILD = textwrap.dedent("""
 
     grid = SlotGrid("g", rows=2, cols=2, base_capacity={"LUT": 400},
                     max_util=1.0)
-    res = search_until_converged(chain(), grid,
-                                 space=SearchSpace(utils=(0.7, 0.85, 1.0)),
-                                 rounds=3, points_per_round=6,
-                                 sim_firings=50, checkpoint=sys.argv[1])
+    plan = FaultPlan.from_dict(json.loads(sys.argv[2]))
+    with install_faults(plan):
+        res = search_until_converged(
+            chain(), grid, space=SearchSpace(utils=(0.7, 0.85, 1.0)),
+            rounds=3, points_per_round=6, sim_firings=50,
+            checkpoint=sys.argv[1])
     print(f"done rounds_run={res.rounds_run} "
           f"resumed_rounds={res.resumed_rounds}")
 """)
 
 
-def _child_env(plan=None):
+def _child_env():
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env = dict(os.environ)
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    env.pop(faults.ENV_VAR, None)
-    if plan is not None:
-        env[faults.ENV_VAR] = json.dumps(plan.as_dict())
     return env
 
 
@@ -278,8 +288,9 @@ def test_sigkill_between_rounds_then_resume_is_bit_identical(tmp_path):
     ckpt = tmp_path / "ckpt"
     # 1) the victim: SIGKILLs itself right after the round-0 checkpoint
     victim = subprocess.run(
-        [sys.executable, "-c", _CHILD, str(ckpt)],
-        env=_child_env(FaultPlan(kill_after_round=0)),
+        [sys.executable, "-c", _CHILD, str(ckpt),
+         json.dumps(FaultPlan(kill_after_round=0).as_dict())],
+        env=_child_env(),
         capture_output=True, text=True)
     assert victim.returncode == -signal.SIGKILL, victim.stderr
     assert (ckpt / "state_r0000.pkl").exists()

@@ -13,16 +13,10 @@ from _propcheck import given, settings, strategies as st
 from repro.core import (SimJob, TaskGraphBuilder, pipeline_headroom,
                         simulate, simulate_batch)
 from repro.core.graph import Stream, Task, TaskGraph
-from repro.core.simulate import _jax_ready
 # the corpus fuzz family subsumes the old ad-hoc helper: layered DAGs
 # with random fanin, zero-depth FIFOs, control streams, detached sinks,
 # skip edges and (allow_cycle) occasional feedback cycles
 from repro.corpus import random_graph as _random_graph
-
-#: does backend="auto" promote to the jitted sweep in this environment?
-_HAVE_JAX = _jax_ready()
-jax_only = pytest.mark.skipif(not _HAVE_JAX, reason="jax not installed")
-
 
 def _assert_engines_agree(g, **kw):
     ev = simulate(g, engine="event", **kw)
@@ -196,8 +190,7 @@ def test_fast_subset_designs_vectorize_with_exact_results():
               B.bucket_sort(), B.page_rank()]
     jobs = [SimJob(g) for g in graphs]
     vec = simulate_batch(jobs, firings=50)
-    want = "jax-padded" if _HAVE_JAX else "numpy-padded"
-    assert all(r.engine == want for r in vec)
+    assert all(r.engine == "jax-padded" for r in vec)
     ref = [simulate(g, firings=50) for g in graphs]
     for a, b in zip(vec, ref):
         assert (a.cycles, a.fired, a.deadlocked) == \
@@ -211,9 +204,8 @@ def test_engine_invocation_counters():
     from repro.core import engine_counts, reset_engine_counts
     jobs = _random_mixed_jobs(7)
     simulate_batch(jobs, firings=10)
-    expected = {"event": 0, "cycle": 0, "numpy": 0, "jax": 0, "fallback": 0}
-    expected["jax" if _HAVE_JAX else "numpy"] = 1
-    assert engine_counts() == expected
+    assert engine_counts() == {"event": 0, "cycle": 0, "numpy": 0, "jax": 1,
+                               "fallback": 0}
     reset_engine_counts()
     simulate_batch(jobs, firings=10, backend="event")
     counts = engine_counts()
@@ -225,7 +217,6 @@ def test_engine_invocation_counters():
 # jax-jitted backend (bit-exact against the NumPy oracle)
 # ---------------------------------------------------------------------------
 
-@jax_only
 @settings(max_examples=15, deadline=None)
 @given(st.integers(0, 99_999))
 def test_jax_backend_three_way_equivalence(seed):
@@ -246,7 +237,6 @@ def test_jax_backend_three_way_equivalence(seed):
             (b.cycles, b.fired, b.deadlocked)
 
 
-@jax_only
 def test_auto_promotes_to_jax():
     """backend="auto" resolves to the jitted sweep when jax imports and the
     knobs are int32-safe — with zero fallback ticks."""
@@ -259,7 +249,6 @@ def test_auto_promotes_to_jax():
     assert counts["fallback"] == 0
 
 
-@jax_only
 def test_jax_chunking_matches_unchunked():
     """max_bytes chunking splits the jax sweep exactly like the NumPy one:
     one engine invocation per chunk, results identical to the whole-batch
@@ -275,7 +264,6 @@ def test_jax_chunking_matches_unchunked():
             (b.cycles, b.fired, b.deadlocked)
 
 
-@jax_only
 def test_jax_backend_int32_guard_raises():
     """Forcing backend="jax" past the sweep's int32 range is an error, not
     a silent degrade."""
@@ -284,7 +272,6 @@ def test_jax_backend_int32_guard_raises():
         simulate_batch(jobs, firings=10, max_cycles=1 << 31, backend="jax")
 
 
-@jax_only
 def test_auto_int32_overflow_degrades_to_numpy_with_fallback_tick():
     """auto with int32-unsafe knobs degrades to the NumPy backend — but
     audibly: a warning plus an engine_counts()["fallback"] tick (what the
@@ -299,7 +286,6 @@ def test_auto_int32_overflow_degrades_to_numpy_with_fallback_tick():
     assert counts["jax"] == 0
 
 
-@jax_only
 def test_jax_compile_cache_reuses_shapes():
     """Recompilation is keyed by the bucketed padded shape only: re-running
     the same batch with different scalar knobs (firings/max_cycles are

@@ -272,7 +272,7 @@ class DiskStoreMachine(RuleBasedStateMachine):
     @rule(w=machine_st.integers(0, 1), i=machine_st.integers(0, 11))
     def put(self, w, i):
         key, value = ("k", i), f"verdict for {i}"
-        with faults.install(self.PLAN, env=False):
+        with faults.install(self.PLAN):
             self.writers[w].record_infeasible(key, value)
         self.model.setdefault(key, value)
         self.wrote[w].add(key)

@@ -17,6 +17,9 @@ from repro.optim import (adafactor_init, adafactor_update, adamw_init,
                          adamw_update, clip_by_global_norm, cosine_schedule,
                          zero1_specs)
 
+#: the checkout root, wherever it was copied to
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 def _quad_problem():
     key = jax.random.PRNGKey(0)
@@ -105,6 +108,43 @@ def test_checkpoint_roundtrip(tmp_path):
     assert int(back["opt"]["step"]) == 7
 
 
+def test_compile_cache_dir_from_env(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, the entry-point helper sets no
+    other directory and compiled programs land there."""
+    script = (
+        "import jax, jax.numpy as jnp\n"
+        "from repro import compile_cache\n"
+        "print(compile_cache.configure())\n"
+        "jax.config.update('jax_persistent_cache_min_compile_time_secs', 0)\n"
+        "jax.jit(lambda x: jnp.sin(x) @ x.T)(jnp.ones((64, 64)))"
+        ".block_until_ready()\n")
+    cache = tmp_path / "jax-cache"
+    env = dict(os.environ, PYTHONPATH="src",
+               JAX_COMPILATION_CACHE_DIR=str(cache))
+    r = subprocess.run([sys.executable, "-c", script], env=env, cwd=ROOT,
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert r.stdout.split() == [str(cache)]
+    assert any(cache.iterdir())
+
+
+def test_compile_cache_default_dir(monkeypatch):
+    """Without it: one fixed, git-ignored directory inside the checkout."""
+    from repro import compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        path = compile_cache.configure()
+        assert path == compile_cache.configure()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    assert path == os.path.join(ROOT, ".jax_cache")
+    with open(os.path.join(ROOT, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+
+
 def test_train_restart_after_failure(tmp_path):
     """FT driver: crash at step 30, restart resumes from the checkpoint."""
     env = dict(os.environ, PYTHONPATH="src")
@@ -112,11 +152,11 @@ def test_train_restart_after_failure(tmp_path):
             "granite-8b", "--reduced", "--steps", "60", "--batch", "2",
             "--seq", "32", "--ckpt-dir", str(tmp_path), "--ckpt-every",
             "20", "--log-every", "100"]
-    r = subprocess.run(base + ["--fail-at", "30"], env=env, cwd="/root/repo",
+    r = subprocess.run(base + ["--fail-at", "30"], env=env, cwd=ROOT,
                        capture_output=True, text=True)
     assert r.returncode == 42, r.stderr[-2000:]
     assert latest_step(str(tmp_path)) == 20
-    r = subprocess.run(base, env=env, cwd="/root/repo",
+    r = subprocess.run(base, env=env, cwd=ROOT,
                        capture_output=True, text=True)
     assert r.returncode == 0, r.stderr[-2000:]
     assert "restoring from step 20" in r.stdout

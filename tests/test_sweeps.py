@@ -18,11 +18,7 @@ from repro.core import (PhysicalModel, SearchPoint, SearchSpace,
                         TaskGraphBuilder, analyze_timing,
                         explore_design_space, sweep_backends)
 from repro.core import explorer as explorer_mod
-from repro.core.simulate import _jax_ready
 from repro.fpga import grid_for, tpu_pod_grid, u250_grid, u280_grid
-
-jax_only = pytest.mark.skipif(not _jax_ready(), reason="jax not installed")
-
 
 def _vecadd(pe=4):
     b = TaskGraphBuilder("VecAdd")
@@ -221,7 +217,6 @@ def test_fmax_suite_fast_subset_is_one_padded_sweep():
         assert r["backend_used"] == "numpy-padded"
 
 
-@jax_only
 def test_fmax_suite_jax_backend_matches_numpy_rows():
     """Acceptance for the jitted backend at the suite level: scoring the
     same designs with ``backend="jax"`` reproduces the NumPy rows exactly
