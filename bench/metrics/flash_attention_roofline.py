@@ -1,0 +1,21 @@
+"""Share of its roofline that the prefill attention kernel reaches: the
+least time the chip could take for the traced prefills' attention (per
+layer, the larger of ops / peak FLOP/s and bytes / peak bytes/s, from
+``bench/flops.py``) over the kernel's summed device time, in percent."""
+from bench import flops, serve_trace
+
+
+def read(ctx):
+    if ctx.trace is None or ctx.peak is None:
+        return None
+    least = secs = 0.0
+    layers = ctx.spec["num_hidden_layers"]
+    for b, (lo, hi) in serve_trace.traced_batches(ctx):
+        runs = serve_trace.step_runs(ctx.trace, lo, hi, b.gen)
+        if runs is None:
+            return None
+        t, _ = flops.least_time(
+            *flops.flash_attention_cost(ctx.spec, b.B, b.S), ctx.peak)
+        least += layers * t
+        secs += serve_trace.kernel_seconds(ctx.trace, runs[:1])
+    return 100.0 * least / secs if secs else None
