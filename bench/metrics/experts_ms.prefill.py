@@ -1,0 +1,9 @@
+"""Device time of the expert layer (``experts`` scope: router, dispatch,
+expert matmuls, combine) per prefill call of the traced batches, in ms
+(8192 prompt tokens a call in chat-prefill).  Read through the trace's
+HLO (``bench/layer_time.py``)."""
+from bench import layer_time
+
+
+def read(ctx):
+    return layer_time.ms_per_run(ctx, "prefill", "experts")

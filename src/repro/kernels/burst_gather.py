@@ -104,5 +104,6 @@ def burst_gather(table: jax.Array, idx: jax.Array, *, ib: int = DEFAULT_IB,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Np, Dp), table.dtype),
         interpret=interpret,
+        name="burst_gather",
     )(idxp, table)
     return out[:N, :D]

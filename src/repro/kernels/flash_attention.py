@@ -98,8 +98,10 @@ def _kernel(klen_ref, qoff_ref, q_ref, k_ref, v_ref, o_ref,
 
 def flash_attention(q, k, v, *, causal=True, window=None, softcap=None,
                     scale=None, q_offset=0, kv_len=None,
-                    qb=DEFAULT_QB, kb=DEFAULT_KB, interpret=False):
-    """q: (B, Sq, Hq, D); k, v: (B, Skv, Hkv, D) -> (B, Sq, Hq, D)."""
+                    qb=DEFAULT_QB, kb=DEFAULT_KB, interpret=False,
+                    name="flash_attention"):
+    """q: (B, Sq, Hq, D); k, v: (B, Skv, Hkv, D) -> (B, Sq, Hq, D).
+    ``name`` is the kernel's name in the compiled program and its trace."""
     B, Sq, Hq, D = q.shape
     _, Skv, Hkv, _ = k.shape
     g = Hq // Hkv
@@ -155,6 +157,7 @@ def flash_attention(q, k, v, *, causal=True, window=None, softcap=None,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, Sq_p, Dp), q.dtype),
         interpret=interpret,
+        name=name,
     )(klen, qoff, qp, kp, vp)
     return out.transpose(0, 2, 1, 3)[:, :Sq, :, :D]
 
@@ -169,5 +172,6 @@ def decode_attention(q, k, v, *, softcap=None, scale=None, q_offset=0,
     qp = jnp.pad(q, ((0, 0), (0, 7), (0, 0), (0, 0)))
     out = flash_attention(qp, k, v, causal=False, window=None,
                           softcap=softcap, scale=scale, q_offset=q_offset,
-                          kv_len=kv_len, qb=8, interpret=interpret)
+                          kv_len=kv_len, qb=8, interpret=interpret,
+                          name="decode_attention")
     return out[:, :1]

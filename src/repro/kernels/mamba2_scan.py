@@ -130,6 +130,7 @@ def mamba2_scan(x, dt, A, B_, C, state=None, *, chunk=DEFAULT_CHUNK,
         ],
         scratch_shapes=[pltpu.VMEM((Pp, Np), jnp.float32)],
         interpret=interpret,
+        name="mamba2_scan",
     )(xp, dtp, Ar, Bp, Cp, h0)
     y = y.transpose(0, 2, 1, 3)[:, :S, :, :P]
     return y, hout[:, :, :P, :N]

@@ -76,5 +76,6 @@ def moe_gmm(x: jax.Array, w: jax.Array, group_ids: jax.Array, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Tp, Np), x.dtype),
         interpret=interpret,
+        name="moe_gmm",
     )(gids, xp, wp)
     return out[:T, :N]

@@ -144,6 +144,7 @@ def rwkv6_scan(r, k, v, w, u, state=None, *, chunk=DEFAULT_CHUNK,
         ],
         scratch_shapes=[pltpu.VMEM((Dp, Dp), jnp.float32)],
         interpret=interpret,
+        name="rwkv6_scan",
     )(rp, kp, vp, lwp, up, s0)
     y = y.transpose(0, 2, 1, 3)[:, :S, :, :D]
     return y, sout[:, :, :D, :D]

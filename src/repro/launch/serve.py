@@ -45,6 +45,20 @@ def init_params(cfg: ArchConfig, seed: int = 0):
         jax.random.PRNGKey(seed))
 
 
+def step_programs(cfg: ArchConfig):
+    """``lm.step`` jitted twice, as ``prefill`` and ``decode``, with the
+    cache donated: the programs are named ``jit_prefill`` and
+    ``jit_decode`` in their HLO and on a profiler trace's module line."""
+    def prefill(params, cache, tokens):
+        return lm.step(params, cfg, cache, tokens)
+
+    def decode(params, cache, tokens):
+        return lm.step(params, cfg, cache, tokens)
+
+    return (jax.jit(prefill, donate_argnums=(1,)),
+            jax.jit(decode, donate_argnums=(1,)))
+
+
 def generate(params, cfg: ArchConfig, prompts: jax.Array, *, gen: int,
              extra=None) -> Generation:
     """Prefill ``prompts`` (B, S), then decode ``gen`` tokens greedily.
@@ -52,10 +66,10 @@ def generate(params, cfg: ArchConfig, prompts: jax.Array, *, gen: int,
     the host waits only at the end of each timed phase."""
     B, S = prompts.shape
     cache = lm.init_cache(params, cfg, B, max_seq=S + gen, extra=extra)
-    step = jax.jit(lambda p, c, t: lm.step(p, cfg, c, t), donate_argnums=(1,))
+    prefill, decode = step_programs(cfg)
     t0 = time.perf_counter()
-    prefill = step.lower(params, cache, prompts).compile()
-    decode = step.lower(params, cache, prompts[:, :1]).compile()
+    prefill = prefill.lower(params, cache, prompts).compile()
+    decode = decode.lower(params, cache, prompts[:, :1]).compile()
     compile_s = time.perf_counter() - t0
 
     def greedy(logits):

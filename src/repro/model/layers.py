@@ -3,6 +3,11 @@
 Everything is bf16 by default with fp32 norms/softmax internals.  The
 attention / SSM hot spots route through ``repro.kernels.ops``; ``kernel``
 picks the Pallas TPU kernel over the XLA reference (see ``lm.step``).
+
+Each layer of the model runs under a ``jax.named_scope`` (``embed``,
+``attention``, ``experts`` with ``router`` inside, ``mlp``, ``mixer``,
+``head``), which every instruction it compiles to carries in its op path,
+so a device trace's operations can be put under the model's layers.
 """
 from __future__ import annotations
 
@@ -113,6 +118,7 @@ class AttnSpec:
     causal: bool = True
 
 
+@jax.named_scope("attention")
 def attn_apply(p, cfg: ArchConfig, spec: AttnSpec, x, *, positions,
                cache=None, kv_from=None, kv_len=None, kernel=False):
     """x: (B, S, d).  cache: optional dict(k, v, pos) for decode.
@@ -204,6 +210,7 @@ def mlp_init(cfg: ArchConfig, key, d_ff=None):
     return p
 
 
+@jax.named_scope("mlp")
 def mlp_apply(p, cfg: ArchConfig, x):
     act = jax.nn.silu if cfg.mlp_act == "silu" else \
         (lambda a: jax.nn.gelu(a, approximate=True))

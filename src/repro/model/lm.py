@@ -223,6 +223,7 @@ def _block_apply(p, cfg: ArchConfig, kind: str, spec: AttnSpec, x, *,
 # forward passes
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("embed")
 def _embed(params, cfg: ArchConfig, tokens, *, kernel=False):
     B, S = tokens.shape
     x = ops.burst_gather(params["embed"], tokens.reshape(-1), kernel=kernel)
@@ -271,6 +272,7 @@ def apply_group(gp, cfg: ArchConfig, specs, x, *, positions, x0=None,
     return x, aux, new_caches
 
 
+@jax.named_scope("head")
 def lm_head(params, cfg: ArchConfig, x):
     """Final norm + (tied) LM head + optional softcap.  Returns logits over
     the PADDED vocab with pad rows masked to -inf (shard-friendly)."""

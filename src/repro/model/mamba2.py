@@ -44,6 +44,7 @@ def _causal_conv(x, w, state=None):
     return out, new_state
 
 
+@jax.named_scope("mixer")
 def mamba2_apply(p, cfg: ArchConfig, x, cache=None, *, kernel=False):
     """x: (B, S, d).  cache: {"conv": (B,K-1,C), "ssd": (B,H,P,N), "pos"}."""
     B, S, d = x.shape

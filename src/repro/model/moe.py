@@ -29,6 +29,7 @@ def moe_init(cfg: ArchConfig, key):
     return p
 
 
+@jax.named_scope("experts")
 def moe_apply(p, cfg: ArchConfig, x):
     """x: (B, S, d) -> (y, aux_loss).
 
@@ -39,14 +40,15 @@ def moe_apply(p, cfg: ArchConfig, x):
     e, k = cfg.n_experts, cfg.top_k
     xf = x.reshape(B * S, d)
 
-    logits = (xf.astype(jnp.float32) @ p["router"])           # (T, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_i = jax.lax.top_k(probs, k)                     # (T, k)
-    top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    with jax.named_scope("router"):
+        logits = (xf.astype(jnp.float32) @ p["router"])       # (T, E)
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_p, top_i = jax.lax.top_k(probs, k)                 # (T, k)
+        top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
 
-    # dispatch one-hot: (T, k, E) -> combine weights (T, E)
-    onehot = jax.nn.one_hot(top_i, e, dtype=xf.dtype)          # (T, k, E)
-    combine = (onehot * top_p[..., None].astype(xf.dtype)).sum(1)  # (T, E)
+        # dispatch one-hot: (T, k, E) -> combine weights (T, E)
+        onehot = jax.nn.one_hot(top_i, e, dtype=xf.dtype)      # (T, k, E)
+        combine = (onehot * top_p[..., None].astype(xf.dtype)).sum(1)
 
     # expert compute (dense dispatch einsum — GSPMD shards over E)
     xe = jnp.einsum("te,td->etd", (combine > 0).astype(xf.dtype), xf)

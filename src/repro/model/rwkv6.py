@@ -48,6 +48,7 @@ def _token_shift(x, last):
     return jnp.concatenate([last, x[:, :-1]], axis=1)
 
 
+@jax.named_scope("mixer")
 def time_mix_apply(p, cfg: ArchConfig, x, shift, state, *, kernel=False):
     """x: (B,S,d); shift: (B,1,d) last token of previous chunk;
     state: (B,H,D,D) WKV state.  Returns y, new_shift, new_state."""
@@ -72,6 +73,7 @@ def time_mix_apply(p, cfg: ArchConfig, x, shift, state, *, kernel=False):
     return y @ p["wo"], x[:, -1:], new_state
 
 
+@jax.named_scope("mixer")
 def chan_mix_apply(p, cfg: ArchConfig, x, shift):
     xs = _token_shift(x, shift)
     def mix(i):

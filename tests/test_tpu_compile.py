@@ -10,7 +10,9 @@ library (see the verify notes in the README).
 """
 from __future__ import annotations
 
+import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -19,11 +21,13 @@ from jax.sharding import SingleDeviceSharding
 
 from repro import configs
 from repro.kernels import sim_sweep
+from repro.launch import serve
 from repro.kernels.burst_gather import burst_gather
 from repro.kernels.flash_attention import decode_attention, flash_attention
 from repro.kernels.mamba2_scan import mamba2_scan
 from repro.kernels.moe_gmm import moe_gmm
 from repro.kernels.rwkv6_scan import rwkv6_scan
+from repro.model import lm
 
 GRANITE_MOE = configs.get("granite-moe-3b-a800m")
 RWKV6 = configs.get("rwkv6-1.6b")
@@ -127,3 +131,38 @@ def test_sim_sweep(one_chip):
         ((V, S), i32), ((V, S), i32), ((V, T), i32), ((V, T), i32),
         ((), i32), ((), i32), kernel=False)
     assert compiled.memory_analysis().argument_size_in_bytes > V * S * H * 4
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_serve_step_kernels_are_named(one_chip, monkeypatch, program):
+    """The published-width serve step as ``generate`` compiles it on a TPU:
+    each Pallas call is a custom call named by its kernel's ``name=``, the
+    attention kernel under the ``attention`` scope and the embedding gather
+    under ``embed`` (what ``bench/layer_time.py`` reads from a trace)."""
+    monkeypatch.setattr(lm, "kernels_here", lambda: True)
+    c, B, S = GRANITE_MOE, 8, 256
+
+    def place(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = place(jax.eval_shape(functools.partial(lm.init_params, c),
+                                  jax.random.PRNGKey(0)))
+    cache = place(jax.eval_shape(
+        lambda p: lm.init_cache(p, c, B, max_seq=S + 8), params))
+    step = dict(zip(("prefill", "decode"), serve.step_programs(c)))[program]
+    tokens = jax.ShapeDtypeStruct((B, S if program == "prefill" else 1),
+                                  jnp.int32, sharding=one_chip)
+    text = step.lower(params, cache, tokens).compile().as_text()
+    assert text.startswith(f"HloModule jit_{program},")
+    attn = "flash_attention" if program == "prefill" else "decode_attention"
+    kernels = {}
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            name = re.match(r"\s*(?:ROOT )?%([\w\-]+)\.?\d*\s", line).group(1)
+            kernels[name] = re.search(r'op_name="([^"]*)"', line).group(1)
+    assert sorted(kernels) == sorted([attn, "burst_gather"])
+    assert kernels[attn].startswith(f"jit({program})/while/")
+    assert kernels[attn].endswith(f"/attention/{attn}/pallas_call")
+    assert kernels["burst_gather"] == f"jit({program})/embed/burst_gather/" \
+        "pallas_call"
