@@ -131,8 +131,10 @@ def phase_kernels():
     kd = rnd((4, T, c.n_kv_heads, c.head_dim))
     vd = rnd((4, T, c.n_kv_heads, c.head_dim))
     klen = jnp.array([T, 1500, 700, 1], jnp.int32)
-    got = jax.jit(decode_attention)(qd, kd, vd, q_offset=klen - 1,
-                                    kv_len=klen)
+    # the kernel reads a layer of the model's stacked (L, B, S, Hkv * D)
+    # cache: here the only layer of a stack of one
+    got = jax.jit(decode_attention)(qd, kd.reshape(1, 4, T, -1),
+                                    vd.reshape(1, 4, T, -1), kv_len=klen)
     check("decode_attention", rel_err(got, reference(
         ref.attention_ref, qd, kd, vd, causal=False, kv_len=klen)),
         2e-2, bf16_out)

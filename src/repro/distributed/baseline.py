@@ -72,10 +72,11 @@ def build_serve_step(cfg: ArchConfig):
 
 
 def cache_shardings(cfg: ArchConfig, cache, mesh: Mesh):
-    """KV caches: batch over (pod, data); heads over model when the KV-head
-    count divides, otherwise the cache LENGTH is sharded over model
-    (context parallelism — each chip holds a context slice); SSM states:
-    batch over data axes, heads over model."""
+    """KV caches (G, B, W, Hkv * D), a slot's heads side by side
+    (``layers.attn_cache_init``): batch over (pod, data); the heads over
+    model when the KV-head count divides (whole heads a chip), otherwise
+    the cache LENGTH over model (context parallelism — each chip holds a
+    context slice); SSM states: batch over data axes, heads over model."""
     daxes = data_axes(mesh)
     tp = mesh.shape["model"]
 
@@ -95,12 +96,12 @@ def cache_shardings(cfg: ArchConfig, cache, mesh: Mesh):
         if leaf.ndim == 0:
             return P()
         name = path[-1] if path else ""
-        if name in ("k", "v"):            # (G, B, W, Hkv, D)
-            if leaf.shape[3] % tp == 0:
-                return cut(P(None, daxes, None, "model", None), leaf.ndim)
+        if name in ("k", "v"):            # (G, B, W, Hkv * D)
+            if cfg.n_kv_heads % tp == 0:
+                return P(None, daxes, None, "model")
             if leaf.shape[2] % tp == 0:   # context parallelism fallback
-                return cut(P(None, daxes, "model", None, None), leaf.ndim)
-            return cut(P(None, daxes, None, None, None), leaf.ndim)
+                return P(None, daxes, "model", None)
+            return P(None, daxes, None, None)
         if name in ("ssd", "wkv"):        # (G, B, H, P, N) / (G, B, H, D, D)
             if leaf.shape[2] % tp == 0:
                 return cut(P(None, daxes, "model", None, None), leaf.ndim)

@@ -29,10 +29,29 @@ def attention(q, k, v, *, causal=True, window=None, softcap=None, scale=None,
         return fa.flash_attention(q, k, v, causal=causal, window=window,
                                   softcap=softcap, scale=scale,
                                   q_offset=q_offset, kv_len=kv_len)
-    # single-token decode
-    return fa.decode_attention(q, k, v, softcap=softcap, scale=scale,
-                               q_offset=q_offset, kv_len=kv_len,
-                               window=window)
+    # single-token decode against keys that are no cache (cross-attention):
+    # a stack of one layer with a slot's heads in one row
+    B, S = k.shape[:2]
+    return fa.decode_attention(q, k.reshape(1, B, S, -1),
+                               v.reshape(1, B, S, -1), softcap=softcap,
+                               scale=scale, kv_len=kv_len)
+
+
+def cached_attention(q, k_cache, v_cache, layer, *, kv_len, softcap=None,
+                     scale=None, kernel=False):
+    """One new token (B, 1, Hq, D) against layer ``layer`` of stacked KV
+    caches (L, B, W, Hkv * D) (``layers.attn_cache_init``).  The kernel
+    reads the layer where it lies; the reference slices it out."""
+    if not kernel:
+        B, _, _, D = q.shape
+        W = k_cache.shape[2]
+        k = k_cache[layer].reshape(B, W, -1, D)
+        v = v_cache[layer].reshape(B, W, -1, D)
+        return _ref.attention_ref(q, k, v, causal=False, softcap=softcap,
+                                  scale=scale, kv_len=kv_len)
+    from . import flash_attention as fa
+    return fa.decode_attention(q, k_cache, v_cache, layer=layer,
+                               kv_len=kv_len, softcap=softcap, scale=scale)
 
 
 def mamba2_scan(x, dt, A, B, C, state=None, *, kernel=False):

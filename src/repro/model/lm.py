@@ -252,24 +252,19 @@ def _memory(params, cfg: ArchConfig, extra):
 
 
 def apply_group(gp, cfg: ArchConfig, specs, x, *, positions, x0=None,
-                memory=None, shared=None, caches=None):
-    """Apply one layer-group (len(cfg.layer_pattern) blocks, unrolled).
-    caches: per-position cache list or None.  Returns (x, aux, caches)."""
+                memory=None, shared=None):
+    """Apply one layer-group (len(cfg.layer_pattern) blocks, unrolled),
+    without caches.  Returns (x, aux, None)."""
     aux = jnp.zeros((), jnp.float32)
-    new_caches = [] if caches is not None else None
     h_idx = 0
     for i, ch in enumerate(cfg.layer_pattern):
-        ci = caches[i] if caches is not None else None
-        x, a, ci = _block_apply(gp[i], cfg, ch, specs[i], x,
-                                positions=positions, x0=x0, memory=memory,
-                                cache=ci, shared=shared,
-                                shared_idx=h_idx % 2)
+        x, a, _ = _block_apply(gp[i], cfg, ch, specs[i], x,
+                               positions=positions, x0=x0, memory=memory,
+                               shared=shared, shared_idx=h_idx % 2)
         if ch == "H":
             h_idx += 1
         aux = aux + a
-        if new_caches is not None:
-            new_caches.append(ci)
-    return x, aux, new_caches
+    return x, aux, None
 
 
 @jax.named_scope("head")
@@ -422,33 +417,65 @@ def step(params, cfg: ArchConfig, cache, tokens, *, unroll: bool = False):
     shared = params.get("shared")
     x0 = x
 
+    # the stacked caches ride in the loop's carry, so each layer reads and
+    # writes its own in place: attention K/V stay whole (``attn_apply``
+    # and the decode kernel index layer g), other states are sliced out
+    # and written back
     def group_fn(carry, scanned):
-        x, aux = carry
-        gp, gc = scanned
-        new_gc = []
+        x, aux, groups = carry
+        gp, g = scanned
+        groups = list(groups)
         h_idx = 0
         for i, ch in enumerate(cfg.layer_pattern):
-            ci = _with_pos(gc[i], pos0)
+            ci = _with_pos(_layer_cache(groups[i], g), pos0)
             x, a, ci = _block_apply(gp[i], cfg, ch, specs[i], x,
                                     positions=positions, x0=x0,
                                     memory=memory, cache=ci, shared=shared,
                                     shared_idx=h_idx % 2, kernel=kernel)
             if ch == "H":
                 h_idx += 1
-            new_gc.append(ci)
+            groups[i] = _put_layer_cache(groups[i], ci, g)
             aux = aux + a
-        return (x, aux), new_gc
+        return (x, aux, groups), None
 
     n_groups = cfg.n_layers // len(cfg.layer_pattern)
-    (x, _), new_groups = jax.lax.scan(
-        group_fn, (x, jnp.zeros((), jnp.float32)),
-        (params["groups"], cache["groups"]),
+    (x, _, new_groups), _ = jax.lax.scan(
+        group_fn, (x, jnp.zeros((), jnp.float32), cache["groups"]),
+        (params["groups"], jnp.arange(n_groups)),
         unroll=n_groups if unroll else 1)
     logits = lm_head(params, cfg, x[:, -1:])[:, 0]
     new_cache = dict(cache)
     new_cache["groups"] = new_groups
     new_cache["pos"] = pos0 + S
     return logits, new_cache
+
+
+def _is_kv(d):
+    return isinstance(d, dict) and "k" in d and "v" in d
+
+
+def _layer_cache(stacked, g):
+    """Layer g's view of a stacked cache: an attention cache keeps its
+    whole K/V stacks and gains the layer index, every other leaf is
+    sliced at g."""
+    if _is_kv(stacked):
+        return {**stacked, "pos": stacked["pos"][g], "layer": g}
+    if isinstance(stacked, dict):
+        return {k: _layer_cache(v, g) for k, v in stacked.items()}
+    return stacked[g]
+
+
+def _put_layer_cache(stacked, new, g):
+    """Write layer g's updated cache back into the stack: attention K/V
+    were written in place and are taken whole, every other leaf is
+    written at g."""
+    if _is_kv(stacked):
+        return {"k": new["k"], "v": new["v"],
+                "pos": stacked["pos"].at[g].set(new["pos"])}
+    if isinstance(stacked, dict):
+        return {k: _put_layer_cache(v, new[k], g) for k, v in stacked.items()}
+    return jax.lax.dynamic_update_index_in_dim(
+        stacked, new.astype(stacked.dtype), g, 0)
 
 
 def _with_pos(cache_leaf, pos):

@@ -9,7 +9,9 @@ import subprocess
 import sys
 import textwrap
 
+import jax
 import pytest
+from jax.sharding import PartitionSpec as P
 
 from repro import configs
 from repro.distributed.sharding import (HBM_PER_CHIP, TpuPlan, col_slots_for,
@@ -88,6 +90,31 @@ def test_refined_mesh_raises_on_unequal_stages():
                    crossing_cost=0.0)
     with pytest.raises(ValueError, match="equal device slabs"):
         refined_mesh(mesh, plan)
+
+
+@pytest.mark.parametrize("tp, seq, want", [
+    (2, 64, P(None, ("pod", "data"), None, "model")),     # whole heads a chip
+    (4, 64, P(None, ("pod", "data"), "model", None)),     # 2 KV heads: length
+    (4, 30, P(None, ("pod", "data"), None, None)),        # neither divides
+])
+def test_kv_cache_shardings(tp, seq, want):
+    """chatglm3's 2 KV heads over ``model``: a chip holds whole heads of
+    the (G, B, W, Hkv * D) cache, or, where tp does not divide the heads,
+    a slice of its length; never part of a head."""
+    from jax.sharding import AbstractMesh
+
+    from repro.distributed.baseline import cache_shardings
+    from repro.model import lm
+
+    cfg = configs.get_reduced("chatglm3-6b")
+    assert cfg.n_kv_heads == 2
+    mesh = AbstractMesh((1, 2, tp), ("pod", "data", "model"))
+    params = jax.eval_shape(lambda k: lm.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda p: lm.init_cache(p, cfg, 4, max_seq=seq),
+                           params)
+    attn = cache_shardings(cfg, cache, mesh)["groups"][0]
+    assert attn["k"].spec == want and attn["v"].spec == want
 
 
 def test_collective_summary_parsing():

@@ -69,17 +69,48 @@ def test_flash_attention_kv_len_and_offset():
                                rtol=2e-5, atol=2e-5)
 
 
-def test_decode_attention():
-    key = jax.random.PRNGKey(1)
-    q = rand(key, (2, 1, 4, 32), jnp.bfloat16)
-    k = rand(jax.random.fold_in(key, 1), (2, 64, 2, 32), jnp.bfloat16)
-    v = rand(jax.random.fold_in(key, 2), (2, 64, 2, 32), jnp.bfloat16)
-    kv_len = jnp.array([40, 64])
-    want = ref.attention_ref(q, k, v, causal=False, kv_len=kv_len)
-    got = decode_attention(q, k, v, kv_len=kv_len, interpret=True)
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("softcap", [None, 30.0])
+@pytest.mark.parametrize("B, S, Hq, Hkv, D, kv_len", [
+    (2, 64, 4, 2, 32, [40, 64]),                      # the first case
+    (3, 48, 4, 4, 64, [1, 17, 48]),                   # g 1 (MHA)
+    (9, 40, 6, 2, 64, [1, 2, 3, 8, 9, 20, 39, 40, 40]),  # g 3; B 9 over 8
+    (2, 64, 14, 2, 128, None),                        # g 7; whole cache
+    (2, 136, 16, 1, 128, [1, 136]),                   # g 16
+    (2, 24, 4, 2, 256, [5, 24]),                      # D 256
+    (4, 1100, 12, 4, 128, [1, 512, 1024, 1100]),      # KV blocks, ragged end
+])
+def test_decode_attention(B, S, Hq, Hkv, D, kv_len, softcap, dtype):
+    """Against the reference at group sizes 1-16 and head dims 64-256, with
+    ragged ``kv_len`` (1, KV block boundaries, the full cache) or none, a
+    batch that is no multiple of the sequence block and, in the last
+    case, a cache cut into KV blocks of which the last runs past its end
+    (bf16: 1024 and 76 slots; float32: 512, 512 and 76).  The cache is
+    the second layer of a stack of two, as the model keeps it."""
+    key = jax.random.PRNGKey(B * S + D)
+    q = rand(key, (B, 1, Hq, D), dtype)
+    k = rand(jax.random.fold_in(key, 1), (B, S, Hkv, D), dtype)
+    v = rand(jax.random.fold_in(key, 2), (B, S, Hkv, D), dtype)
+    other = rand(jax.random.fold_in(key, 3), (B, S, Hkv * D), dtype)
+    kst = jnp.stack([other, k.reshape(B, S, Hkv * D)])
+    vst = jnp.stack([other, v.reshape(B, S, Hkv * D)])
+    kv_len = None if kv_len is None else jnp.array(kv_len)
+    want = ref.attention_ref(q, k, v, causal=False, softcap=softcap,
+                             kv_len=kv_len)
+    got = decode_attention(q, kst, vst, layer=1, softcap=softcap,
+                           kv_len=kv_len, interpret=True)
     np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32), rtol=2e-2,
-                               atol=2e-2)
+                               np.asarray(want, np.float32), **tol(dtype))
+
+
+def test_decode_blocks():
+    """Decode-heavy's cache (B 64 x 256 slots, 8 KV heads of 64, bf16)
+    takes 16 grid steps of 4 sequences, whole caches; chat-prefill's
+    longest (B 4 x 2,056) is cut into KV blocks of 1,024 slots."""
+    from repro.kernels.flash_attention import decode_blocks
+    assert decode_blocks(64, 256, 8 * 64 * 2) == (4, 256)
+    assert decode_blocks(4, 2056, 8 * 64 * 2) == (1, 1024)
+    assert decode_blocks(3, 16, 2 * 32 * 2) == (3, 16)
 
 
 # ---------------------------------------------------------------------------

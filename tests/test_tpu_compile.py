@@ -11,6 +11,7 @@ library (see the verify notes in the README).
 from __future__ import annotations
 
 import functools
+import math
 import os
 import re
 
@@ -73,15 +74,23 @@ def test_flash_attention_prefill(one_chip, dtype):
              ((1, TOKENS, c.n_kv_heads, c.head_dim), dtype))
 
 
-def test_decode_attention(one_chip):
-    c = GRANITE_MOE
+@pytest.mark.parametrize("B, S, cfg, softcap", [
+    (64, 256, "granite-moe-3b-a800m", None),     # decode-heavy's step
+    (4, 2056, "granite-moe-3b-a800m", None),     # chat-prefill's longest
+    (4, TOKENS, "granite-moe-3b-a800m", None),
+    (8, 4096, "gemma2-27b", 50.0),               # D 128, soft-capped
+])
+def test_decode_attention(one_chip, B, S, cfg, softcap):
+    """Layer ``layer`` (traced) of a stack of every layer's cache, as the
+    model's layer loop hands it over."""
+    c = configs.get(cfg)
     bf = jnp.bfloat16
-    _compile(lambda q, k, v, pos: decode_attention(
-        q, k, v, q_offset=pos, kv_len=jnp.full((4,), pos + 1)), one_chip,
-        ((4, 1, c.n_heads, c.head_dim), bf),
-        ((4, TOKENS, c.n_kv_heads, c.head_dim), bf),
-        ((4, TOKENS, c.n_kv_heads, c.head_dim), bf),
-        ((), jnp.int32))
+    cache = (c.n_layers, B, S, c.n_kv_heads * c.head_dim)
+    _compile(lambda q, k, v, pos, layer: decode_attention(
+        q, k, v, layer=layer, softcap=softcap,
+        kv_len=jnp.full((B,), pos + 1)), one_chip,
+        ((B, 1, c.n_heads, c.head_dim), bf), (cache, bf), (cache, bf),
+        ((), jnp.int32), ((), jnp.int32))
 
 
 def test_rwkv6_scan(one_chip):
@@ -166,3 +175,48 @@ def test_serve_step_kernels_are_named(one_chip, monkeypatch, program):
     assert kernels[attn].endswith(f"/attention/{attn}/pallas_call")
     assert kernels["burst_gather"] == f"jit({program})/embed/burst_gather/" \
         "pallas_call"
+
+
+def test_decode_step_reads_cache_in_place(one_chip, monkeypatch):
+    """Decode-heavy's step (granite-moe, B 64, a 256-slot cache) as
+    ``generate`` compiles it on a TPU: every layer's K (and V) stays one
+    stack in the layer loop, and no operation of the whole program slices,
+    copies, pads or transposes a layer's cache.  The only operations of
+    that size besides the program's parameters and the loop's tuple are
+    the two writes of the new token's K and V into the stacks, in place,
+    and the decode kernel's K and V operands are those writes: it reads
+    its layer from the stack in HBM, with no copy staged for it."""
+    monkeypatch.setattr(lm, "kernels_here", lambda: True)
+    c, B, S = GRANITE_MOE, 64, 256
+
+    def place(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = place(jax.eval_shape(functools.partial(lm.init_params, c),
+                                  jax.random.PRNGKey(0)))
+    cache = place(jax.eval_shape(
+        lambda p: lm.init_cache(p, c, B, max_seq=S), params))
+    decode = serve.step_programs(c)[1]
+    tokens = jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=one_chip)
+    text = decode.lower(params, cache, tokens).compile().as_text()
+    stack = f"bf16[{c.n_layers},{B},{S},{c.n_kv_heads * c.head_dim}]"
+    big, kernel = {}, None
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = \w+\[([\d,]+)\]\S* "
+                     r"([\w-]+)\((.*)", line)
+        if not m:
+            continue
+        dims = list(map(int, m.group(2).split(",")))
+        if m.group(1).startswith("decode_attention"):
+            kernel = re.findall(r"%([\w.\-]+)", m.group(4))
+        # a layer's cache or more, in any layout: B and S among the dims
+        if (B in dims and S in dims and math.prod(dims)
+                >= B * S * c.n_kv_heads * c.head_dim):
+            big[m.group(1)] = (m.group(3), line.split(" = ")[1].split("{")[0])
+    writes = [n for n, (op, _) in big.items() if op == "dynamic-update-slice"]
+    assert {op for op, _ in big.values()} == {
+        "parameter", "get-tuple-element", "dynamic-update-slice"}, big
+    assert len(writes) == 2
+    assert all(big[n][1] == stack for n in writes)
+    assert kernel is not None and kernel[-2:] == writes
